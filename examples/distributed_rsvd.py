@@ -1,24 +1,21 @@
 """Distributed RSVD on a (data, model) mesh — shard_map SUMMA projection +
-TSQR (DESIGN.md §6).  Uses virtual host devices so it runs anywhere:
+TSQR (DESIGN.md §6).  The mesh spans every device JAX finds: the chips of a
+TPU host, or virtual CPU devices:
 
+    PYTHONPATH=src python examples/distributed_rsvd.py          # TPU host
     XLA_FLAGS=--xla_force_host_platform_device_count=8 \
-      PYTHONPATH=src python examples/distributed_rsvd.py
+      JAX_PLATFORMS=cpu PYTHONPATH=src python examples/distributed_rsvd.py
 """
 
-import os
+import jax
+import jax.numpy as jnp
 
-if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
-    os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=8 "
-                               + os.environ.get("XLA_FLAGS", ""))
-
-import jax                                               # noqa: E402
-import jax.numpy as jnp                                  # noqa: E402
-
-from repro.core import distributed as D, rsvd            # noqa: E402
+from repro.core import distributed as D, rsvd
+from repro.launch.mesh import make_host_mesh
 
 
 def main():
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_host_mesh(model_parallel=2)
     print(f"devices: {len(jax.devices())}, mesh: {dict(mesh.shape)}")
 
     key = jax.random.PRNGKey(0)

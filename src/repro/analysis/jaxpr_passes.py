@@ -35,7 +35,7 @@ from __future__ import annotations
 from typing import Callable, Iterable, Iterator, Optional, Sequence
 
 import jax
-import jax.core as jc
+import jax.extend.core as jc
 import jax.numpy as jnp
 
 from repro.analysis.findings import Finding

@@ -17,8 +17,9 @@ Traces an entry point (abstractly — nothing runs), finds every
   ``(key, offsets)`` pairs).  A wide or high-rank SMEM operand is almost
   always a misplaced tensor that belongs in VMEM.
 
-The index maps are evaluated with ``jax.core.eval_jaxpr`` over the full
-grid product, so audits should trace *small* shapes (a 2x2x2 grid proves
+The index maps are evaluated with ``jax.extend.core.jaxpr_as_fun`` over the
+full grid product (scalar-prefetch operands, which this repo's maps never
+read, are passed as zeros), so audits should trace *small* shapes (a 2x2x2 grid proves
 the same structural property as a 256^3 one).
 """
 
@@ -28,7 +29,8 @@ import itertools
 from typing import Callable, Iterator, Sequence
 
 import jax
-import jax.core as jc
+import jax.extend.core as jc
+import numpy as np
 
 from repro.analysis.findings import Finding
 from repro.analysis.jaxpr_passes import iter_eqns
@@ -49,19 +51,17 @@ def _dimension_semantics(eqn, n_axes: int) -> tuple[str, ...]:
     """parallel/arbitrary per grid axis; unknown -> all parallel (the
     conservative choice: more pairs must prove disjoint)."""
     cp = eqn.params.get("compiler_params") or {}
-    if hasattr(cp, "get"):
-        mosaic = cp.get("mosaic") or {}
-        sem = (mosaic.get("dimension_semantics")
-               if hasattr(mosaic, "get")
-               else getattr(mosaic, "dimension_semantics", None))
-        if sem:
-            return tuple(sem)
+    sem = getattr(cp.get("mosaic_tpu"), "dimension_semantics", None)
+    if sem:
+        return tuple(str(getattr(d, "value", d)).lower() for d in sem)
     return ("parallel",) * n_axes
 
 
 def _eval_index_map(bm, point: Sequence[int]) -> tuple[int, ...]:
     cj = bm.index_map_jaxpr
-    out = jc.eval_jaxpr(cj.jaxpr, cj.consts, *point)
+    extra = [np.zeros(v.aval.shape, v.aval.dtype)
+             for v in cj.jaxpr.invars[len(point):]]
+    out = jc.jaxpr_as_fun(cj)(*point, *extra)
     return tuple(int(x) for x in out)
 
 
@@ -91,7 +91,8 @@ def audit_pallas(fn: Callable, *args, what: str = "kernel",
         for bm in gm.block_mappings:
             if not _is_smem(bm):
                 continue
-            shape = tuple(int(s) for s in bm.block_shape)
+            shape = tuple(int(getattr(s, "block_size", s))
+                          for s in bm.block_shape)
             ok = (len(shape) == 2 and shape[0] == 1
                   and shape[1] in tuple(smem_widths))
             if not ok:

@@ -29,7 +29,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.core.projection import ProjectionMethod, gaussian, project
 
 
@@ -62,6 +61,13 @@ def _local_sketch_fused(a_blk, key2, p_hat: int, model_axis: str,
     return jax.lax.psum(y, model_axis)
 
 
+def _dot(a, b):
+    """f32 GEMM at full precision: a TPU's default f32 dot is one bf16
+    pass, which put distributed rSVD at 16x the single-device residual."""
+    return jnp.dot(a, b, precision=jax.lax.Precision.HIGHEST,
+                   preferred_element_type=jnp.float32)
+
+
 def _tsqr(y_blk: jax.Array, data_axis: str) -> tuple[jax.Array, jax.Array]:
     """Tall-skinny QR across the data axis.  y_blk: (m_local, p)."""
     p = y_blk.shape[1]
@@ -70,7 +76,7 @@ def _tsqr(y_blk: jax.Array, data_axis: str) -> tuple[jax.Array, jax.Array]:
     q2, r = jnp.linalg.qr(r_all.reshape(-1, p))        # (dp*p, p) QR
     idx = jax.lax.axis_index(data_axis)
     q2_blk = jax.lax.dynamic_slice_in_dim(q2, idx * p, p, axis=0)
-    return jnp.dot(q1, q2_blk, preferred_element_type=jnp.float32), r
+    return _dot(q1, q2_blk), r
 
 
 def distributed_range_finder(key, a: jax.Array, p_hat: int, mesh: Mesh, *,
@@ -94,7 +100,7 @@ def distributed_range_finder(key, a: jax.Array, p_hat: int, mesh: Mesh, *,
             q, _ = _tsqr(y, data_axis)
             return q
 
-        return compat.shard_map(
+        return jax.shard_map(
             fn_fused, mesh=mesh,
             in_specs=(P(data_axis, model_axis), P(None, None)),
             out_specs=P(data_axis, None), check_vma=False,
@@ -108,7 +114,7 @@ def distributed_range_finder(key, a: jax.Array, p_hat: int, mesh: Mesh, *,
         q, _ = _tsqr(y, data_axis)
         return q
 
-    return compat.shard_map(
+    return jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P(data_axis, model_axis), P(model_axis, None)),
         out_specs=P(data_axis, None), check_vma=False,
@@ -154,27 +160,22 @@ def distributed_rsvd(key, a: jax.Array, rank: int, mesh: Mesh, *,
         q, _ = _tsqr(y, data_axis)                     # (m_loc, p_hat)
         for _ in range(power_iters):
             # z = A^T q : (n_loc, p_hat), psum over data
-            z = jax.lax.psum(
-                jnp.dot(a_blk.T, q, preferred_element_type=jnp.float32),
-                data_axis)
+            z = jax.lax.psum(_dot(a_blk.T, q), data_axis)
             z, _ = _tsqr(z, model_axis)
             # y = A z : (m_loc, p_hat), psum over model
-            y = jax.lax.psum(
-                jnp.dot(a_blk, z, preferred_element_type=jnp.float32),
-                model_axis)
+            y = jax.lax.psum(_dot(a_blk, z), model_axis)
             q, _ = _tsqr(y, data_axis)
         # Line 3: B = Q^T A, cols sharded over model.
-        b_blk = jax.lax.psum(
-            jnp.dot(q.T, a_blk, preferred_element_type=jnp.float32), data_axis)
+        b_blk = jax.lax.psum(_dot(q.T, a_blk), data_axis)
         # Line 4 WITHOUT Gram squaring (would double the condition number):
         # TSQR of B^T across model -> B = R^T Q_bt^T; small SVD of R^T.
         q_bt, r_bt = _tsqr(b_blk.T, model_axis)        # (n_loc, p), (p, p)
         u_b, s, wt = jnp.linalg.svd(r_bt.T, full_matrices=False)
-        vt_blk = jnp.dot(wt, q_bt.T)                   # (p, n_loc) sharded
-        u = jnp.dot(q, u_b, preferred_element_type=jnp.float32)
+        vt_blk = _dot(wt, q_bt.T)                      # (p, n_loc) sharded
+        u = _dot(q, u_b)
         return u[:, :rank], s[:rank], vt_blk[:rank, :]
 
-    u, s, vt = compat.shard_map(
+    u, s, vt = jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P(data_axis, model_axis), aux_spec),
         out_specs=(P(data_axis, None), P(), P(None, model_axis)),
@@ -200,7 +201,7 @@ def _shard_map_stack(fn, items, mesh: Mesh, axis: str):
     def body(item):
         return fn(jax.tree.map(lambda x: jnp.squeeze(x, 0), item))
 
-    return compat.shard_map(body, mesh=mesh, in_specs=(P(axis),),
+    return jax.shard_map(body, mesh=mesh, in_specs=(P(axis),),
                             out_specs=P(), check_vma=False)(stacked)
 
 

@@ -12,10 +12,9 @@ Methods:
   * ``shgemm_pallas``— same math via the Pallas TPU kernel (kernels/shgemm.py).
   * ``shgemm_fused`` — zero-HBM sketching: Omega is generated inside the
                        Pallas kernel from a PRNG key (kernels/shgemm_fused.py)
-                       and never materialized — use ``sketch`` (key-based)
-                       rather than ``project`` (Omega-based) to get the
-                       benefit; ``project`` with this method falls back to
-                       the materialized Pallas kernel.
+                       and never materialized.  Only ``sketch`` (key-based)
+                       takes it; ``project`` (Omega-based) refuses it, since
+                       a materialized Omega leaves nothing to fuse.
 
 Random matrices: Gaussian (stored f32/bf16/fp16), Achlioptas sparse {-1,0,+1}
 (Eq. 5), very-sparse (Li et al., s = sqrt(n) of the data dimension), and
@@ -139,8 +138,15 @@ def _dot_f32(a: jax.Array, b: jax.Array) -> jax.Array:
 
 
 def _dot_mxu(a_lowp: jax.Array, b_lowp: jax.Array) -> jax.Array:
-    """One MXU pass: low-precision inputs, f32 accumulation (TPU semantics)."""
-    return jnp.dot(a_lowp, b_lowp, preferred_element_type=jnp.float32)
+    """One MXU pass: low-precision inputs, f32 accumulation (TPU semantics).
+
+    fp16 is not an MXU input format on TPU v5e: at default precision XLA
+    feeds it through one bf16 pass, which drops 3 of its 11 bits.  fp16
+    operands therefore take ``HIGHEST``, whose bf16 passes keep their
+    products exact (DESIGN.md §2)."""
+    fp16 = jnp.float16 in (a_lowp.dtype, b_lowp.dtype)
+    return jnp.dot(a_lowp, b_lowp, preferred_element_type=jnp.float32,
+                   precision=jax.lax.Precision.HIGHEST if fp16 else None)
 
 
 def shgemm_jnp(a_f32: jax.Array, b_lowp: jax.Array) -> jax.Array:
@@ -178,11 +184,16 @@ def project(a: jax.Array, omega: jax.Array,
         hi, mid, lo = split_fp32_bf16_3(a)
         b = omega.astype(jnp.bfloat16)
         return (_dot_mxu(hi, b) + _dot_mxu(mid, b) + _dot_mxu(lo, b))
-    if method in ("shgemm_pallas", "shgemm_fused"):
-        # With a materialized Omega there is nothing left to fuse: the fused
-        # method degrades gracefully to the materialized Pallas kernel.
+    if method == "shgemm_pallas":
         from repro.kernels import ops  # deferred: keeps core import-light
         return ops.shgemm(a.astype(jnp.float32), omega)
+    if method == "shgemm_fused":
+        raise ValueError(
+            "project() takes a materialized Omega, so there is nothing for "
+            "method='shgemm_fused' to fuse: call sketch(key, a, p, "
+            "method='shgemm_fused') to generate Omega in the kernel, or "
+            "project(..., method='shgemm_pallas') for the materialized "
+            "Pallas kernel")
     raise ValueError(f"unknown projection method {method!r}")
 
 

@@ -33,6 +33,19 @@ FP16_SCALE = 2.0**11
 FP16_INV_SCALE = 2.0**-11
 
 
+def _round_to(a: jax.Array, dtype) -> jax.Array:
+    """``a`` rounded to nearest in ``dtype``'s precision, kept in f32.
+
+    A ``reduce_precision`` op, not an f32 -> ``dtype`` -> f32 round trip: XLA
+    on TPU may skip such a round trip (excess precision), which makes the
+    split's low term ``a - hi`` zero and the split GEMM a single low-precision
+    pass.  For bf16 the bits equal the round trip's; for fp16, values in
+    fp16's subnormal range round to zero and the low term carries them."""
+    fi = jnp.finfo(dtype)
+    return jax.lax.reduce_precision(a, exponent_bits=fi.nexp,
+                                    mantissa_bits=fi.nmant)
+
+
 def split_fp32_bf16(a: jax.Array) -> tuple[jax.Array, jax.Array]:
     """Split f32 ``a`` into (hi, lo) bf16 with a ~ hi + lo.
 
@@ -42,9 +55,8 @@ def split_fp32_bf16(a: jax.Array) -> tuple[jax.Array, jax.Array]:
     (paper §4.3 / [34]).
     """
     a = a.astype(jnp.float32)
-    hi = a.astype(jnp.bfloat16)
-    lo = (a - hi.astype(jnp.float32)).astype(jnp.bfloat16)
-    return hi, lo
+    hi = _round_to(a, jnp.bfloat16)
+    return hi.astype(jnp.bfloat16), (a - hi).astype(jnp.bfloat16)
 
 
 def split_fp32_fp16(a: jax.Array) -> tuple[jax.Array, jax.Array]:
@@ -54,9 +66,8 @@ def split_fp32_fp16(a: jax.Array) -> tuple[jax.Array, jax.Array]:
     reproducing the paper's §5.1.1 Cauchy failure mode (used in benchmarks).
     """
     a = a.astype(jnp.float32)
-    hi = a.astype(jnp.float16)
-    lo = ((a - hi.astype(jnp.float32)) * FP16_SCALE).astype(jnp.float16)
-    return hi, lo
+    hi = _round_to(a, jnp.float16)
+    return hi.astype(jnp.float16), ((a - hi) * FP16_SCALE).astype(jnp.float16)
 
 
 def split_fp32_bf16_3(a: jax.Array) -> tuple[jax.Array, jax.Array, jax.Array]:
@@ -68,11 +79,11 @@ def split_fp32_bf16_3(a: jax.Array) -> tuple[jax.Array, jax.Array, jax.Array]:
     the MXU work (still half of XLA's 6-pass f32 emulation).
     """
     a = a.astype(jnp.float32)
-    hi = a.astype(jnp.bfloat16)
-    r1 = a - hi.astype(jnp.float32)
-    mid = r1.astype(jnp.bfloat16)
-    lo = (r1 - mid.astype(jnp.float32)).astype(jnp.bfloat16)
-    return hi, mid, lo
+    hi = _round_to(a, jnp.bfloat16)
+    mid = _round_to(a - hi, jnp.bfloat16)
+    lo = a - hi - mid
+    return (hi.astype(jnp.bfloat16), mid.astype(jnp.bfloat16),
+            lo.astype(jnp.bfloat16))
 
 
 def split_fp32(a: jax.Array, fmt: SplitFormat = "bf16") -> tuple[jax.Array, jax.Array]:

@@ -145,12 +145,10 @@ def decode_cache_key(s: int, g: int, hd: int, r: int,
 
 
 def timing_mode(interpret: bool | None = None) -> str:
-    """The mode a timing run (or the current pick) executes under.  Default
-    mirrors the ``ops`` dispatch rule: everything but a real TPU backend
-    runs the Pallas kernels in interpret mode."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    return "interpret" if interpret else "compiled"
+    """The mode a timing run (or the current pick) executes under — the
+    ``ops`` dispatch rule (``ops.resolve_interpret``)."""
+    from repro.kernels.ops import resolve_interpret  # deferred: ops imports us
+    return "interpret" if resolve_interpret(interpret) else "compiled"
 
 
 def _entry_usable(entry: dict, mode: str) -> bool:

@@ -42,26 +42,25 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.shgemm import CompilerParams
-
 NEG_INF = -1e30
 
 
 def _fdec_kernel(comp_ref, wp_ref, q_ref, k_ref, v_ref, kus_ref, kvt_ref,
                  vus_ref, vvt_ref, o_ref, s_ref, m_ref, l_ref, accd_ref,
-                 accf_ref, *, scale, cap, block_kv):
+                 accf_ref, *, scale, cap, block_kv, kv_heads):
     """Grid: (B*KV, n_kv); kv innermost ('arbitrary').
 
     q_ref: (1, G, hd) — G = q heads per kv head.  k/v_ref: (1, bkv, hd);
-    kus/vus_ref: (1, bkv, r); kvt/vvt_ref: (1, r, hd).  comp_ref/wp_ref:
-    (1, 1) int32 in SMEM (per-slot compressed-prefix length, slot clock).
+    kus/vus_ref: (1, bkv, r); kvt/vvt_ref: (1, r, hd).  comp_ref (B,) and
+    wp_ref (1,): int32 scalar-prefetch operands in SMEM (per-slot
+    compressed-prefix length, slot clock).
     Scratch: s (1, G, bkv) block scores; m/l (1, G, 1); acc_d (1, G, hd);
     acc_f (1, G, r) — the prefix value contraction stays rank-r until the
     final ``acc_f·vt_v`` in the epilogue.
     """
     ik = pl.program_id(1)
-    comp = comp_ref[0, 0]
-    wp = wp_ref[0, 0]
+    comp = comp_ref[pl.program_id(0) // kv_heads]
+    wp = wp_ref[0]
     start = ik * block_kv
     g = q_ref.shape[1]
     pos = start + jax.lax.broadcasted_iota(jnp.int32, (g, block_kv), 1)
@@ -177,29 +176,24 @@ def factored_decode_attention(q, k, v, k_us, k_vt, v_us, v_vt, comp_len,
     vus = _pad_seq(v_us, 2, s_pad).reshape(b * kvh, s_pad, r)
     kvt = k_vt.reshape(b * kvh, r, hd)
     vvt = v_vt.reshape(b * kvh, r, hd)
-    comp = comp_len.astype(jnp.int32).reshape(b, 1)
-    wp = jnp.asarray(write_pos, jnp.int32).reshape(1, 1)
+    # whole-array SMEM operands via scalar prefetch: Mosaic refuses a (1, 1)
+    # SMEM block over a (B, 1) array (the (8, 128) block rule)
+    comp = comp_len.astype(jnp.int32).reshape(b)
+    wp = jnp.asarray(write_pos, jnp.int32).reshape(1)
 
-    grid = (b * kvh, s_pad // block_kv)
-    out = pl.pallas_call(
-        functools.partial(_fdec_kernel, scale=scale, cap=cap,
-                          block_kv=block_kv),
-        grid=grid,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b * kvh, s_pad // block_kv),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda bh, ik: (bh // kvh, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1), lambda bh, ik: (0, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, g, hd), lambda bh, ik: (bh, 0, 0)),
-            pl.BlockSpec((1, block_kv, hd), lambda bh, ik: (bh, ik, 0)),
-            pl.BlockSpec((1, block_kv, hd), lambda bh, ik: (bh, ik, 0)),
-            pl.BlockSpec((1, block_kv, r), lambda bh, ik: (bh, ik, 0)),
-            pl.BlockSpec((1, r, hd), lambda bh, ik: (bh, 0, 0)),
-            pl.BlockSpec((1, block_kv, r), lambda bh, ik: (bh, ik, 0)),
-            pl.BlockSpec((1, r, hd), lambda bh, ik: (bh, 0, 0)),
+            pl.BlockSpec((1, g, hd), lambda bh, ik, *_: (bh, 0, 0)),
+            pl.BlockSpec((1, block_kv, hd), lambda bh, ik, *_: (bh, ik, 0)),
+            pl.BlockSpec((1, block_kv, hd), lambda bh, ik, *_: (bh, ik, 0)),
+            pl.BlockSpec((1, block_kv, r), lambda bh, ik, *_: (bh, ik, 0)),
+            pl.BlockSpec((1, r, hd), lambda bh, ik, *_: (bh, 0, 0)),
+            pl.BlockSpec((1, block_kv, r), lambda bh, ik, *_: (bh, ik, 0)),
+            pl.BlockSpec((1, r, hd), lambda bh, ik, *_: (bh, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, g, hd), lambda bh, ik: (bh, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * kvh, g, hd), q.dtype),
+        out_specs=pl.BlockSpec((1, g, hd), lambda bh, ik, *_: (bh, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((1, g, block_kv), jnp.float32),   # block scores
             pltpu.VMEM((1, g, 1), jnp.float32),          # running max
@@ -207,7 +201,13 @@ def factored_decode_attention(q, k, v, k_us, k_vt, v_us, v_vt, comp_len,
             pltpu.VMEM((1, g, hd), jnp.float32),         # dense-tail acc
             pltpu.VMEM((1, g, r), jnp.float32),          # factored acc
         ],
-        compiler_params=CompilerParams(
+    )
+    out = pl.pallas_call(
+        functools.partial(_fdec_kernel, scale=scale, cap=cap,
+                          block_kv=block_kv, kv_heads=kvh),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b * kvh, g, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(comp, wp, qr, kr, vr, kus, kvt, vus, vvt)

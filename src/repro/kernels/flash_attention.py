@@ -30,8 +30,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import shgemm as _shgemm
-
 NEG_INF = -1e30
 
 
@@ -127,7 +125,7 @@ def flash_attention(q, k, v, *, causal: bool = True, scale: float = None,
             pltpu.VMEM((1, g, block_q, 1), jnp.float32),
             pltpu.VMEM((1, g, block_q, hd), jnp.float32),
         ],
-        compiler_params=_shgemm.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qr, kr, vr)

@@ -1,9 +1,11 @@
 """Public jit'd wrappers around the Pallas kernels.
 
 ``shgemm(a, b)`` handles arbitrary shapes/dtypes: pads to block multiples,
-dispatches to the Pallas kernel (interpret=True automatically on CPU), strips
-padding.  This is the drop-in used by core/projection.py's "shgemm_pallas"
-method and by the serving/optimizer layers.
+dispatches to the Pallas kernel, strips padding.  Every wrapper here runs its
+kernel compiled on a TPU backend and in interpret mode on any other
+(``resolve_interpret``); an explicit ``interpret=`` wins.  ``shgemm`` is
+the drop-in used by core/projection.py's "shgemm_pallas" method and by the
+serving/optimizer layers.
 
 ``shgemm_fused(a, key, n)`` is the zero-HBM-Omega variant: the random matrix
 is generated inside the kernel from ``key`` (kernels/shgemm_fused.py), so the
@@ -27,8 +29,24 @@ from repro.kernels import shgemm as _k
 from repro.kernels import shgemm_fused as _kf
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def resolve_interpret(interpret: bool | None) -> bool:
+    """Compiled on a TPU backend, interpret mode everywhere else; an explicit
+    bool wins (the chip-compile tests pass False on a CPU host)."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return interpret
+
+
+def _refuse_compiled_fp16(dtype, interpret: bool) -> None:
+    """fp16 Omega does not compile in either Pallas kernel on TPU v5e: Mosaic
+    refuses the f16 vector load (materialized kernel) and the f16
+    ``tpu.pack_subelements`` (fused kernel).  Refuse it loudly instead of
+    converting; interpret mode keeps fp16 for CPU validation."""
+    if jnp.dtype(dtype) == jnp.float16 and not interpret:
+        raise ValueError(
+            "fp16 Omega does not compile in the Pallas kernels on TPU: store "
+            "Omega in bfloat16 (omega_dtype=jnp.bfloat16), or take the "
+            "paper's fp16 path through XLA with method='shgemm'")
 
 
 def _pad_to(x: jax.Array, m0: int, m1: int) -> jax.Array:
@@ -54,8 +72,9 @@ def shgemm(a: jax.Array, b: jax.Array, *, blocks: tuple[int, int, int] | None = 
            terms: int = 2, interpret: bool | None = None) -> jax.Array:
     """C_f32 = A_f32 @ B_lowp for arbitrary shapes.
 
-    B may be bf16 (TPU-native) or fp16 (paper-faithful path).  A is cast to
-    f32 if needed.  On non-TPU backends the kernel runs in interpret mode
+    B may be bf16 (TPU-native) or fp16 (paper-faithful path; interpret mode
+    only — compiled, it raises, see ``_refuse_compiled_fp16``).  A is cast
+    to f32 if needed.  On non-TPU backends the kernel runs in interpret mode
     (Python evaluation of the kernel body) for bit-accurate validation.
 
     Block resolution happens OUTSIDE the jit boundary (the wrapper itself is
@@ -70,8 +89,8 @@ def shgemm(a: jax.Array, b: jax.Array, *, blocks: tuple[int, int, int] | None = 
     k2, n = b.shape
     if k != k2:
         raise ValueError(f"contraction mismatch: {a.shape} @ {b.shape}")
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = resolve_interpret(interpret)
+    _refuse_compiled_fp16(b.dtype, interpret)
     if blocks is None:
         blocks = _tune.pick_blocks(m, n, k, b_dtype=b.dtype, terms=terms,
                                    interpret=interpret)
@@ -147,8 +166,8 @@ def shgemm_fused(a: jax.Array, key: jax.Array, n: int, *,
         compute_dtype = store_dtype
     else:
         raise TypeError(f"omega_dtype must be bf16/fp16/fp8, got {omega_dtype}")
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = resolve_interpret(interpret)
+    _refuse_compiled_fp16(compute_dtype, interpret)
     if blocks is None:
         blocks = _tune.pick_blocks(m, n, k, b_dtype=compute_dtype,
                                    terms=terms, fused=True,
@@ -173,19 +192,20 @@ def shgemm_fused(a: jax.Array, key: jax.Array, n: int, *,
 def flash_attention(q, k, v, *, causal: bool = True, scale=None,
                     interpret: bool | None = None):
     """Padded/dispatching wrapper over kernels.flash_attention: pads S to a
-    block multiple (extra kv masked by the causal structure; for non-causal
-    the pad rows are sliced off and pad kv contribute exp(-inf)=0)."""
+    block multiple (the pad kv sit above the causal diagonal, and the pad
+    rows are sliced off).  Non-causal input must already be a block
+    multiple: the kernel has no kv mask, so pad kv would enter the
+    softmax."""
     from repro.kernels import flash_attention as fa
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = resolve_interpret(interpret)
     b, s, h, hd = q.shape
     block = 128 if s >= 128 else max(8, s)
     pad = (-s) % block
     if pad and not causal:
-        # padded kv columns would pollute a non-causal softmax; use the
-        # jnp oracle for ragged non-causal shapes (rare: encoder smoke)
-        from repro.kernels.ref import flash_attention_ref
-        return flash_attention_ref(q, k, v, causal=False, scale=scale)
+        raise ValueError(
+            f"non-causal flash attention needs S to be a multiple of the "
+            f"{block}-row kv block, got S={s}: pad kv would enter the "
+            f"softmax — use models.layers.attention for ragged shapes")
     if pad:
         widths = ((0, 0), (0, pad), (0, 0), (0, 0))
         q = jnp.pad(q, widths)
@@ -210,8 +230,7 @@ def factored_decode_attention(q, k, v, k_us, k_vt, v_us, v_vt, comp_len,
     (``pick_decode_block``) unless given explicitly.
     """
     from repro.kernels import factored_decode as fd
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = resolve_interpret(interpret)
     b, _, h, hd = q.shape
     skv, kvh = k.shape[1], k.shape[2]
     g = h // kvh

@@ -30,10 +30,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.splitting import FP16_INV_SCALE, FP16_SCALE
 
-# jax renamed ``TPUCompilerParams`` -> ``CompilerParams``; support both so the
-# kernel builds across the 0.4.x / 0.5.x line.
-CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 # Default tile sizes: MXU is 128x128; (8, 128) f32 VMEM tiling.  (256,256,512)
 # keeps the working set ~1.1 MB (~2.2 MB double-buffered) << 16 MB VMEM while
 # amortizing the VPU split over a deep K tile.  See EXPERIMENTS.md §Perf for
@@ -105,7 +101,7 @@ def shgemm_pallas(a: jax.Array, b: jax.Array, *, bm: int = DEFAULT_BM,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -56,7 +56,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.splitting import FP16_INV_SCALE, FP16_SCALE
-from repro.kernels.shgemm import CompilerParams
 
 SKETCH_DISTS = ("gaussian", "achlioptas", "very_sparse")
 
@@ -94,8 +93,13 @@ def counter_bits(k0: jax.Array, k1: jax.Array, rows: jax.Array,
 
 
 def _uniform24(bits: jax.Array, offset: float = 0.0) -> jax.Array:
-    """Top 24 bits -> f32 uniform on [0,1) (+offset shifts off exact zero)."""
-    return (bits >> 8).astype(jnp.float32) * _TWO_NEG_24 + offset
+    """Top 24 bits -> f32 uniform on [0,1) (+offset shifts off exact zero).
+
+    The conversion goes through int32: Mosaic has no uint32 -> f32 cast, and
+    a 24-bit value is non-negative in int32 and exact in f32, so the result
+    is the same on every backend."""
+    return ((bits >> 8).astype(jnp.int32).astype(jnp.float32) * _TWO_NEG_24
+            + offset)
 
 
 def sample_tile(k0: jax.Array, k1: jax.Array, rows: jax.Array,
@@ -275,7 +279,7 @@ def shgemm_fused_pallas(a: jax.Array, key2: jax.Array, n: int, *,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

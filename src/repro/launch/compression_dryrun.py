@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.launch import dryrun as DR
 from repro.launch import mesh as mesh_mod
 
@@ -35,10 +34,10 @@ def main(d: int = 8192, cols: int = 4096, rank: int = 64):
     def raw(g):
         def f(gl):
             return jax.lax.psum(gl, "pod")
-        return compat.shard_map(f, mesh=mesh,
-                             in_specs=P(None, ("data", "model")),
-                             out_specs=P(None, ("data", "model")),
-                             check_vma=False)(g)
+        return jax.shard_map(f, mesh=mesh,
+                          in_specs=P(None, ("data", "model")),
+                          out_specs=P(None, ("data", "model")),
+                          check_vma=False)(g)
 
     def sketched(g):
         def f(gl):
@@ -48,10 +47,10 @@ def main(d: int = 8192, cols: int = 4096, rank: int = 64):
             sk = jnp.dot(q.astype(jnp.bfloat16).T.astype(jnp.float32), gl)
             sk = jax.lax.psum(sk, "pod")          # rank-r rows on the wire
             return jnp.dot(q, sk)
-        return compat.shard_map(f, mesh=mesh,
-                             in_specs=P(None, ("data", "model")),
-                             out_specs=P(None, ("data", "model")),
-                             check_vma=False)(g)
+        return jax.shard_map(f, mesh=mesh,
+                          in_specs=P(None, ("data", "model")),
+                          out_specs=P(None, ("data", "model")),
+                          check_vma=False)(g)
 
     rows = []
     for name, fn in (("raw_psum", raw), ("sketched_psum", sketched)):

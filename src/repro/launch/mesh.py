@@ -1,4 +1,10 @@
-"""Production meshes.
+"""Meshes.
+
+Every mesh in the repo is built by ``make_mesh`` here, so every axis is
+``Auto``: since JAX 0.9 ``jax.make_mesh`` makes ``Explicit`` axes by
+default, and the repo's sharding (``with_sharding_constraint`` rules,
+``shard_map`` over a 2-D layout, sharded GEMMs left to the partitioner) is
+written for ``Auto`` axes.
 
 ``make_production_mesh`` is a FUNCTION (not a module constant) so importing
 this module never touches jax device state.  The dry-run sets
@@ -12,23 +18,33 @@ data-parallel axis across the DCN.
 
 from __future__ import annotations
 
+from typing import Sequence
+
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
+
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str], *,
+              devices=None) -> Mesh:
+    """``jax.make_mesh`` with every axis ``Auto`` (see module docstring)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(model_parallel: int = 1) -> Mesh:
-    """Mesh over whatever devices exist (tests / local runs)."""
+    """(data, model) mesh over whatever devices exist; ``model_parallel``
+    falls back to 1 when it does not divide the device count."""
     n = len(jax.devices())
     if n % model_parallel:
         model_parallel = 1
-    return jax.make_mesh((n // model_parallel, model_parallel),
-                         ("data", "model"))
+    return make_mesh((n // model_parallel, model_parallel), ("data", "model"))
 
 
 # Hardware constants for the roofline model (TPU v5e per chip).

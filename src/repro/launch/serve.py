@@ -32,6 +32,7 @@ import jax
 
 from repro._atomic_io import atomic_write_json
 from repro.configs.base import smoke_config
+from repro.launch.compile_cache import configure_compile_cache
 from repro.models import registry as R
 from repro.models import transformer as T
 from repro.serve import loadgen
@@ -134,7 +135,8 @@ def run_engine(args, cfg) -> None:
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-0.6b", choices=sorted(R.ARCHS))
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action="store_true",
+                    help="reduced same-family config (CPU-sized)")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--max-seq", type=int, default=256)
@@ -170,6 +172,7 @@ def main():
 
     logging.basicConfig(level=logging.INFO,
                         format="%(asctime)s %(name)s %(message)s")
+    configure_compile_cache()
     cfg = R.get_arch(args.arch)
     if args.smoke:
         cfg = smoke_config(cfg)

@@ -22,6 +22,8 @@ import numpy as np
 
 from repro.configs.base import smoke_config
 from repro.data.pipeline import MemmapTokens, SyntheticLM
+from repro.launch.compile_cache import configure_compile_cache
+from repro.launch.mesh import make_host_mesh
 from repro.models import registry as R
 from repro.models import transformer as T
 from repro.optim import compression
@@ -30,13 +32,6 @@ from repro.sharding import rules
 from repro.train.loop import LoopConfig, train
 
 log = logging.getLogger("repro.launch.train")
-
-
-def build_mesh(model_parallel: int):
-    devices = jax.devices()
-    n = len(devices)
-    mp = model_parallel if n % model_parallel == 0 else 1
-    return jax.make_mesh((n // mp, mp), ("data", "model"))
 
 
 def main():
@@ -65,12 +60,13 @@ def main():
 
     if "JAX_COORDINATOR" in os.environ:  # multi-host pod slice
         jax.distributed.initialize()
+    configure_compile_cache()
 
     cfg = R.get_arch(args.arch)
     if args.smoke:
         cfg = smoke_config(cfg)
 
-    mesh = build_mesh(args.model_parallel)
+    mesh = make_host_mesh(args.model_parallel)
     act_sharding.set_mesh(mesh, tp=rules.tp_enabled(cfg)
                           and mesh.shape["model"] > 1)
     log.info("mesh %s | arch %s (%.1fM params) | tp=%s",

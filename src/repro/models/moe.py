@@ -21,7 +21,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.models.layers import activation
 
 
@@ -112,7 +111,7 @@ def moe_block(cfg, p, x):
             out = jax.lax.psum(out, "model")
             return out.reshape(bl, sl, d)
 
-        out = compat.shard_map(
+        out = jax.shard_map(
             fn, mesh=mesh,
             in_specs=(P(ba, None, None), P(None, None),
                       P("model", None, None), P("model", None, None),

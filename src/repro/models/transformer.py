@@ -23,7 +23,6 @@ import jax
 import jax.ad_checkpoint
 import jax.numpy as jnp
 
-from repro import compat
 from repro.configs.base import LayerSpec, ModelCfg
 from repro.models import layers as L
 from repro.models import mla as mla_mod
@@ -550,10 +549,10 @@ def embed_tokens(cfg, params, tokens):
             mask = ((tok >= lo) & (tok < lo + vloc))[..., None]
             return jax.lax.psum(jnp.where(mask, vals, 0), "model")
 
-        x = compat.shard_map(lookup, mesh=mesh,
-                          in_specs=(P(ba, None), P("model", None)),
-                          out_specs=P(ba, None, None),
-                          check_vma=False)(tokens, table)
+        x = jax.shard_map(lookup, mesh=mesh,
+                       in_specs=(P(ba, None), P("model", None)),
+                       out_specs=P(ba, None, None),
+                       check_vma=False)(tokens, table)
     else:
         x = table[tokens].astype(_act_dtype(cfg))
     x = constrain(x, "batch", None, None)
@@ -672,10 +671,10 @@ def cross_entropy(logits: jax.Array, labels: jax.Array,
             gold = jax.lax.psum(jnp.where(owned, g, 0.0), "model")
             return logz - gold
 
-        nll = compat.shard_map(vp_nll, mesh=mesh,
-                            in_specs=(P(ba, None, "model"), P(ba, None)),
-                            out_specs=P(ba, None),
-                            check_vma=False)(logits, safe)
+        nll = jax.shard_map(vp_nll, mesh=mesh,
+                         in_specs=(P(ba, None, "model"), P(ba, None)),
+                         out_specs=P(ba, None),
+                         check_vma=False)(logits, safe)
     else:
         lg = L.softcap(logits.astype(jnp.float32), final_softcap)
         logz = jax.nn.logsumexp(lg, axis=-1)

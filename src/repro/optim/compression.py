@@ -66,6 +66,13 @@ def _draw_basis(key, i: int, d: int, rank: int,
     return q_basis
 
 
+def _gemm_method(method: ProjectionMethod) -> ProjectionMethod:
+    """Projection method for a GEMM against the materialized basis Q: the
+    fused method has no Omega left to generate there, so its GEMM is the
+    materialized Pallas kernel."""
+    return "shgemm_pallas" if method == "shgemm_fused" else method
+
+
 def compress_and_reduce(grads, state: CompressionState, *, rank: int = 32,
                         axis_name: Optional[str] = None,
                         method: ProjectionMethod = "shgemm",
@@ -88,7 +95,7 @@ def compress_and_reduce(grads, state: CompressionState, *, rank: int = 32,
         q_low = q_basis.astype(jnp.bfloat16)
         acc = g.astype(jnp.float32) + e
         # sketch: (r, d_in) — mixed-precision projection of acc^T
-        sketch = project(acc.T, q_low, method=method).T
+        sketch = project(acc.T, q_low, method=_gemm_method(method)).T
         if axis_name:
             sketch = jax.lax.psum(sketch, axis_name)
             n_dp = jax.lax.psum(1, axis_name)
@@ -147,7 +154,8 @@ def begin_accumulation(state: CompressionState, grads_like, *,
         if e is None:
             return None, None, jnp.zeros_like(g), None
         q_basis = _draw_basis(key, i, g.shape[0], rank, method)
-        sketch = project(e.T, q_basis.astype(jnp.bfloat16), method=method).T
+        sketch = project(e.T, q_basis.astype(jnp.bfloat16),
+                         method=_gemm_method(method)).T
         return q_basis, sketch, None, e
 
     flat_g, treedef = jax.tree_util.tree_flatten(grads_like)
@@ -177,7 +185,8 @@ def accumulate_microbatch(ms: MicrobatchSketch, grads, *,
             outs.append((None, None, raw + g, None))
             continue
         g32 = g.astype(jnp.float32)
-        s = s + project(g32.T, q.astype(jnp.bfloat16), method=method).T
+        s = s + project(g32.T, q.astype(jnp.bfloat16),
+                        method=_gemm_method(method)).T
         outs.append((q, s, None, acc + g32))
     unf = lambda j: treedef.unflatten([o[j] for o in outs])  # noqa: E731
     return MicrobatchSketch(bases=unf(0), sketches=unf(1), raw=unf(2),

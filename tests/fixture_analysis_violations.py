@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.shgemm import CompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 
 # --- JAX-NO-GEMM: an "SRHT-style" structured apply that cheats with a GEMM
@@ -49,7 +49,7 @@ def bad_alias_kernel(x):
         in_specs=[pl.BlockSpec((8, 8), lambda i, j: (i, j))],
         out_specs=pl.BlockSpec((8, 8), lambda i, j: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((8, 8), jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=True,
     )(x)

@@ -117,7 +117,7 @@ class TestPallasAudit:
 
     def test_clean_on_disjoint_output_blocks(self):
         from jax.experimental import pallas as pl
-        from repro.kernels.shgemm import CompilerParams
+        from jax.experimental.pallas import tpu as pltpu
 
         def good(x):
             return pl.pallas_call(
@@ -126,7 +126,7 @@ class TestPallasAudit:
                 in_specs=[pl.BlockSpec((8, 8), lambda i, j: (i, j))],
                 out_specs=pl.BlockSpec((8, 8), lambda i, j: (i, j)),
                 out_shape=jax.ShapeDtypeStruct((16, 16), jnp.float32),
-                compiler_params=CompilerParams(
+                compiler_params=pltpu.CompilerParams(
                     dimension_semantics=("parallel", "parallel")),
                 interpret=True,
             )(x)

@@ -16,8 +16,9 @@ _SCRIPT = textwrap.dedent("""
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import Mesh
     from repro.core import distributed as D, rsvd
+    from repro.launch.mesh import make_mesh
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     assert len(jax.devices()) == 8
     key = jax.random.PRNGKey(0)
     a = rsvd.matrix_with_singular_values(
@@ -58,7 +59,6 @@ _SCRIPT = textwrap.dedent("""
     # (key, global column offset) — nothing materialized or communicated for
     # the random matrix (DESIGN.md §9/§10).
     from jax.sharding import PartitionSpec as P
-    from repro import compat
     from repro.core.projection import fused_omega
     from repro.kernels import ops, shgemm_fused as kf
 
@@ -76,7 +76,7 @@ _SCRIPT = textwrap.dedent("""
 
     # the sharded fused projection equals the one-shot projection on the
     # materialized counter-stream Omega up to f32 psum ordering alone
-    fnp = compat.shard_map(
+    fnp = jax.shard_map(
         lambda blk, k2: D._local_sketch_fused(blk, k2, 58, "model"),
         mesh=mesh, in_specs=(P("data", "model"), P(None, None)),
         out_specs=P("data", None), check_vma=False)

@@ -20,10 +20,11 @@ _SCRIPT = textwrap.dedent("""
 
     from repro import stream
     from repro.core import distributed as D, rsvd
+    from repro.launch.mesh import make_mesh
     from repro.data import pipeline
 
     assert len(jax.devices()) == 2
-    mesh = jax.make_mesh((2,), ("hosts",))
+    mesh = make_mesh((2,), ("hosts",))
     key = jax.random.PRNGKey(0)
     m, n, rank = 128, 96, 12
     a = jax.random.normal(jax.random.fold_in(key, 1), (m, n), jnp.float32)

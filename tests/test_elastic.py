@@ -11,12 +11,13 @@ _SCRIPT = textwrap.dedent("""
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
     from repro.train.loop import remesh
+    from repro.launch.mesh import make_mesh
 
     devs = jax.devices()
     assert len(devs) == 8
 
     # start on all 8 devices
-    mesh8 = jax.make_mesh((8, 1), ("data", "model"))
+    mesh8 = make_mesh((8, 1), ("data", "model"))
     params = {"w": jax.device_put(
         jnp.arange(64.0).reshape(8, 8),
         NamedSharding(mesh8, P("data", None)))}

@@ -198,6 +198,54 @@ def test_fp16_fused_path():
                                rtol=1e-5, atol=1e-4)
 
 
+def test_fp16_refused_compiled_kept_in_interpret_mode():
+    """The fp16 decision (DESIGN.md §2): compiled Pallas kernels refuse an
+    fp16 Omega before tracing, naming bf16 and the XLA ``shgemm`` method;
+    interpret mode keeps fp16 for validation (``test_fp16_fused_path``)."""
+    a = jax.random.normal(jax.random.PRNGKey(16), (64, 256), jnp.float32)
+    b16 = proj.fused_omega(KEY, (256, 48), dtype=jnp.float16)
+    with pytest.raises(ValueError, match="bfloat16.*method='shgemm'"):
+        ops.shgemm(a, b16, interpret=False)
+    with pytest.raises(ValueError, match="bfloat16.*method='shgemm'"):
+        ops.shgemm_fused(a, KEY, 48, omega_dtype=jnp.float16,
+                         interpret=False)
+    y = ops.shgemm(a, b16, interpret=True)
+    want = proj.project(a, b16, method="shgemm")
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want),
+                               rtol=1e-5, atol=1e-4)
+
+
+# sha256 of reference_omega(PRNGKey(1234), (64, 48), row_offset=256,
+# col_offset=8) in f32, recorded when _uniform24 converted uint32 -> f32
+# directly; the int32 route Mosaic can lower must give the same bits.
+_OMEGA_SHA256 = {
+    "gaussian":
+        "8f04f1391d8241b37c87c8109429d916f6131dd8c1bdde102f8d8085255834bf",
+    "achlioptas":
+        "1f5f3454cf7b4bbdbf9186da4bc185f57cb26271dab88b4614cb4669903b2ce3",
+    "very_sparse":
+        "3cdc26b6c87ef946729769a18f9ba10be6bf18a4c579d41592e7d73d15040f9e",
+}
+
+
+@pytest.mark.parametrize("dist", sorted(_OMEGA_SHA256))
+def test_uniform24_int32_route_keeps_omega_bits(dist):
+    import hashlib
+    omega = kf.reference_omega(jax.random.PRNGKey(1234), (64, 48), dist=dist,
+                               dtype=jnp.float32, row_offset=256,
+                               col_offset=8)
+    digest = hashlib.sha256(np.asarray(omega).tobytes()).hexdigest()
+    assert digest == _OMEGA_SHA256[dist]
+
+
+def test_uniform24_exact_at_extremes():
+    bits = jnp.asarray([0, 0xFF, 0x100, 0x7FFFFFFF, 0x80000000, 0xFFFFFFFF],
+                       jnp.uint32)
+    want = (np.asarray(bits, np.uint64) >> 8).astype(np.float64) * 2.0**-24
+    np.testing.assert_array_equal(np.asarray(kf._uniform24(bits)),
+                                  want.astype(np.float32))
+
+
 # ---------------------------------------------------------------------------
 # Consumers
 # ---------------------------------------------------------------------------

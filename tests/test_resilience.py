@@ -489,12 +489,13 @@ _DIST_SCRIPT = textwrap.dedent("""
     import jax, jax.numpy as jnp
     from repro import stream
     from repro.core.distributed import distributed_rsvd_streamed
+    from repro.launch.mesh import make_mesh
     from repro.stream import resilience as resil
     import sys, tempfile
     from pathlib import Path
 
     assert len(jax.devices()) == 2
-    mesh = jax.make_mesh((2,), ("data",))
+    mesh = make_mesh((2,), ("data",))
     key = jax.random.PRNGKey(0)
     a = np.asarray(jax.random.normal(jax.random.fold_in(key, 1), (96, 64),
                                      jnp.float32))

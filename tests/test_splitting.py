@@ -20,6 +20,23 @@ _FIXED = np.array([1.0, -1.0, 1e-30, -1e30, 3.14159265, -2.7182818,
                    123456.789, -0.333333343], dtype=np.float32)
 
 
+def test_bf16_split_bits_equal_cast_round_trip():
+    """The split rounds with reduce_precision (which XLA on TPU keeps);
+    on bf16 its bits are those of the f32 -> bf16 -> f32 round trip."""
+    rng = np.random.default_rng(0)
+    a = np.concatenate([_FIXED, (rng.standard_normal(4096)
+                                 * 10.0 ** rng.uniform(-38, 38, 4096))
+                        .astype(np.float32)])
+    hi, lo = splitting.split_fp32_bf16(jnp.asarray(a))
+    hi_cast = jnp.asarray(a).astype(jnp.bfloat16)
+    lo_cast = (jnp.asarray(a) - hi_cast.astype(jnp.float32)).astype(
+        jnp.bfloat16)
+    np.testing.assert_array_equal(np.asarray(hi, np.float32),
+                                  np.asarray(hi_cast, np.float32))
+    np.testing.assert_array_equal(np.asarray(lo, np.float32),
+                                  np.asarray(lo_cast, np.float32))
+
+
 def test_bf16_split_residual_bound():
     """|a - hi - lo| <= u_bf16^2 * |a| (Eq. 44's A_Delta bound, bf16 form)."""
     a = jnp.asarray(_FIXED)

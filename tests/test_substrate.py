@@ -160,7 +160,8 @@ def test_checkpoint_restore_resharded_subprocess(tmp_path):
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
         from repro.train.checkpoint import CheckpointManager
-        mesh = jax.make_mesh((8,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("data",))
         mgr = CheckpointManager({str(tmp_path)!r})
         tpl = {{"w": jnp.zeros((8, 8))}}
         restored, step = mgr.restore(tpl, mesh=mesh, specs={{"w": P("data")}})

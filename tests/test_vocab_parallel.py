@@ -14,6 +14,7 @@ _SCRIPT = textwrap.dedent("""
     from repro.configs.base import smoke_config
     from repro.models import registry as R, transformer as T
     from repro.sharding import activation as A
+    from repro.launch.mesh import make_mesh
 
     cfg = smoke_config(R.get_arch("qwen3-0.6b"))
     params = T.init_params(cfg, jax.random.PRNGKey(0))
@@ -33,7 +34,7 @@ _SCRIPT = textwrap.dedent("""
     l_ref, g_ref = jax.value_and_grad(loss)(params, batch)
 
     # vocab-parallel: 4x2 mesh, shard_map paths
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     A.set_mesh(mesh, tp=False)
     l_vp, g_vp = jax.value_and_grad(loss)(params, batch)
     A.set_mesh(None)
