@@ -83,9 +83,13 @@ class AsyncWriter:
     def submit(self, fn: Callable[[], None]) -> None:
         self._q.put(fn)
 
+    def drain(self) -> None:
+        """Block until the queue drains; a failed write stays stored."""
+        self._q.join()
+
     def wait(self) -> None:
         """Block until the queue drains; raise if any write failed."""
-        self._q.join()
+        self.drain()
         if self._err:
             raise RuntimeError("async checkpoint writer failed") from self._err
 

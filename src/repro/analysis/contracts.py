@@ -146,7 +146,7 @@ def factored_decode_audit() -> list[Finding]:
         lambda *xs: fd.factored_decode_attention(
             *xs, write_pos=s - 1, scale=hd ** -0.5, block_kv=128),
         q, k, v, us, vt, us, vt, comp,
-        what="kernels/factored_decode.py", smem_widths=(1,))
+        what="kernels/factored_decode.py", scalar_prefetch=2)
 
 
 # ---------------------------------------------------------------------------

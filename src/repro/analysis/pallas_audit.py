@@ -1,7 +1,7 @@
 """Pallas kernel auditor: BlockSpec/grid proofs for the repo's kernels.
 
 Traces an entry point (abstractly — nothing runs), finds every
-``pallas_call`` equation, and checks two structural contracts against the
+``pallas_call`` equation, and checks its structural contracts against the
 ``GridMapping`` the call was lowered with:
 
 * **Output-block disjointness** (rule ``PL-WRITE-ALIAS``): enumerating the
@@ -11,16 +11,20 @@ Traces an entry point (abstractly — nothing runs), finds every
   kernel's kv loop); a collision across parallel axes means two
   potentially-concurrent grid steps write the same output window — silent
   data races on a real backend, order-dependent results in interpret mode.
-* **SMEM scalar shape** (rule ``PL-SMEM-SHAPE``): operands placed in SMEM
-  must be tiny 2-D scalars — ``(1, w)`` with ``w`` within the audited
-  width (1 by default; `shgemm_fused` declares width 2 for its
-  ``(key, offsets)`` pairs).  A wide or high-rank SMEM operand is almost
-  always a misplaced tensor that belongs in VMEM.
+  An output index map that reads a scalar-prefetch operand depends on
+  run-time values the enumeration cannot see, so it is a finding too.
+* **SMEM scalar shape** (rule ``PL-SMEM-SHAPE``): SMEM blocks must be tiny
+  2-D scalars — ``(1, w)`` with ``w`` within the audited width (1 by
+  default; `shgemm_fused` declares width 2 for its ``(key, offsets)``
+  pairs) — and the kernel must take exactly the declared number of
+  scalar-prefetch operands, each a whole rank-1 array (`factored_decode`
+  declares two: ``comp_len`` and ``write_pos``).  A wide or high-rank SMEM
+  operand is almost always a misplaced tensor that belongs in VMEM.
 
 The index maps are evaluated with ``jax.extend.core.jaxpr_as_fun`` over the
-full grid product (scalar-prefetch operands, which this repo's maps never
-read, are passed as zeros), so audits should trace *small* shapes (a 2x2x2 grid proves
-the same structural property as a 256^3 one).
+full grid product (scalar-prefetch operands, proven unread, are passed as
+zeros), so audits should trace *small* shapes (a 2x2x2 grid proves the
+same structural property as a 256^3 one).
 """
 
 from __future__ import annotations
@@ -57,6 +61,15 @@ def _dimension_semantics(eqn, n_axes: int) -> tuple[str, ...]:
     return ("parallel",) * n_axes
 
 
+def _reads_prefetch(bm, n_grid: int) -> bool:
+    """Whether ``bm``'s index map uses any operand past the grid indices
+    (the scalar-prefetch refs)."""
+    jaxpr = bm.index_map_jaxpr.jaxpr
+    used = {v for e in jaxpr.eqns for v in e.invars if isinstance(v, jc.Var)}
+    used |= {v for v in jaxpr.outvars if isinstance(v, jc.Var)}
+    return any(v in used for v in jaxpr.invars[n_grid:])
+
+
 def _eval_index_map(bm, point: Sequence[int]) -> tuple[int, ...]:
     cj = bm.index_map_jaxpr
     extra = [np.zeros(v.aval.shape, v.aval.dtype)
@@ -73,9 +86,12 @@ def _is_smem(bm) -> bool:
 
 def audit_pallas(fn: Callable, *args, what: str = "kernel",
                  smem_widths: Sequence[int] = (1,),
+                 scalar_prefetch: int = 0,
                  max_grid_points: int = MAX_GRID_POINTS) -> list[Finding]:
     """Audit every pallas_call reachable from ``fn(*args)``; returns
-    findings (empty = both contracts proven for the traced grid)."""
+    findings (empty = every contract proven for the traced grid).
+    ``scalar_prefetch`` is the number of scalar-prefetch operands each
+    kernel declares."""
     findings: list[Finding] = []
     n_calls = 0
     for eqn in pallas_calls(fn, *args):
@@ -101,10 +117,22 @@ def audit_pallas(fn: Callable, *args, what: str = "kernel",
                     message=(f"SMEM operand ({bm.origin}) of {kname} has "
                              f"block shape {shape}; audited widths are "
                              f"(1, {'/'.join(map(str, smem_widths))})"),
-                    hint="SMEM holds scalars — reshape to (1, 1) (or the "
-                         "kernel's declared scalar width) or move the "
-                         "operand to VMEM",
+                    hint="SMEM holds scalars: use the kernel's declared "
+                         "(1, w) block, pass the whole rank-1 array by "
+                         "scalar prefetch, or move the operand to VMEM",
                     match=f"{what}:smem:{bm.origin}:{shape}"))
+
+        # --- scalar-prefetch operands -----------------------------------
+        pre = [tuple(v.aval.shape) for v in eqn.invars[gm.slice_index_ops]]
+        if len(pre) != scalar_prefetch or any(len(s) != 1 for s in pre):
+            findings.append(Finding(
+                rule="PL-SMEM-SHAPE", file=what, line=0,
+                message=(f"{kname} takes {len(pre)} scalar-prefetch "
+                         f"operand(s) of shapes {pre}; audited "
+                         f"{scalar_prefetch} of rank 1"),
+                hint="scalar prefetch places whole arrays in SMEM: keep "
+                     "them rank-1 and declare their count in the contract",
+                match=f"{what}:prefetch:{pre}"))
 
         # --- output-block disjointness ----------------------------------
         total = 1
@@ -124,6 +152,17 @@ def audit_pallas(fn: Callable, *args, what: str = "kernel",
                         if str(bm.origin) == "outputs"
                         or "output" in str(bm.origin)]
         for oi, bm in enumerate(out_mappings):
+            if _reads_prefetch(bm, len(grid)):
+                findings.append(Finding(
+                    rule="PL-WRITE-ALIAS", file=what, line=0,
+                    message=(f"output {oi} of {kname}: the index map reads "
+                             "a scalar-prefetch operand, so its blocks "
+                             "depend on run-time values the audit cannot "
+                             "enumerate"),
+                    hint="index outputs by grid position only, or audit "
+                         "this kernel by another means",
+                    match=f"{what}:prefetch_index:out{oi}"))
+                continue
             seen: dict[tuple, tuple] = {}
             aliased = False
             for point in itertools.product(*[range(g) for g in grid]):

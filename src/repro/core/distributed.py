@@ -335,9 +335,12 @@ def distributed_rsvd_streamed(key, sources, rank: int, mesh: Mesh, *,
 
     def host_tiles(s, r0, start_local=0):
         off = r0 + start_local
+        it = stream.source_tiles(s, prefetch_depth=prefetch_depth,
+                                 start_row=start_local)
+        if ck is not None:
+            it = ck.guard(it)
         t_last = time.perf_counter()
-        for blk in stream.source_tiles(s, prefetch_depth=prefetch_depth,
-                                       start_row=start_local):
+        for blk in it:
             yield off, blk
             off += blk.shape[0]
             if ck is not None:

@@ -239,9 +239,12 @@ def rp_sthosvd_streamed(key: jax.Array, slabs, dims=None, ranks=None, *,
 
     off = start_row
     tiles_done = start_tile
+    it = stream.source_tiles(src, prefetch_depth=prefetch_depth,
+                             start_row=start_row)
+    if ck is not None:
+        it = ck.guard(it)
     t_last = time.perf_counter()
-    for slab in stream.source_tiles(src, prefetch_depth=prefetch_depth,
-                                    start_row=start_row):
+    for slab in it:
         ts = stream.tucker_update(ts, slab, off)
         off += slab.shape[0]
         tiles_done += 1

@@ -287,6 +287,8 @@ def rsvd_streamed(key: jax.Array, a_blocks, rank: int, *,
         off = start_row
         it = stream.source_tiles(src, prefetch_depth=prefetch_depth,
                                  start_row=start_row)
+        if ck is not None:
+            it = ck.guard(it)
         t_last = time.perf_counter()
         for i, blk in enumerate(it, start=start_tile):
             yield i, off, blk

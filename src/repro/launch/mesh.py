@@ -24,12 +24,10 @@ import jax
 from jax.sharding import AxisType, Mesh
 
 
-def make_mesh(shape: Sequence[int], axes: Sequence[str], *,
-              devices=None) -> Mesh:
+def make_mesh(shape: Sequence[int], axes: Sequence[str]) -> Mesh:
     """``jax.make_mesh`` with every axis ``Auto`` (see module docstring)."""
     return jax.make_mesh(tuple(shape), tuple(axes),
-                         axis_types=(AxisType.Auto,) * len(axes),
-                         devices=devices)
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

@@ -42,7 +42,7 @@ import signal
 import time
 import urllib.error
 from pathlib import Path
-from typing import Callable, Iterator, Optional
+from typing import Callable, Iterable, Iterator, Optional
 
 import jax.numpy as jnp
 import numpy as np
@@ -380,6 +380,17 @@ class SketchJobCheckpointer:
             arrays=arrays, meta=manifest["meta"])
 
     # -- per-tile hooks ----------------------------------------------------
+
+    def guard(self, tiles: Iterable) -> Iterator:
+        """Yield from ``tiles``; if reading them raises, let the checkpoints
+        already cut land before the error propagates, so a retry resumes
+        from the latest one.  A failed write is left for ``wait()``: it must
+        not mask the source's error."""
+        try:
+            yield from tiles
+        except Exception:
+            self._writer.drain()
+            raise
 
     def note_tile(self, seconds: float, tiles: int = 1) -> None:
         """Account ``seconds`` of tile work (this attempt)."""

@@ -140,6 +140,54 @@ class TestPallasAudit:
                            jnp.zeros((8,), jnp.float32), what="no kernel")
         assert len(got) == 1 and "pallas_call" in got[0].message
 
+    @staticmethod
+    def _prefetch_kernel(out_map):
+        """(16, 16) copy over a (2,) parallel grid with one scalar-prefetch
+        operand ``rows``; ``out_map(i, rows)`` picks the output block."""
+        from jax.experimental import pallas as pl
+        from jax.experimental.pallas import tpu as pltpu
+
+        def kernel(rows_ref, x_ref, o_ref):
+            o_ref[...] = x_ref[...]
+
+        def fn(rows, x):
+            return pl.pallas_call(
+                kernel,
+                grid_spec=pltpu.PrefetchScalarGridSpec(
+                    num_scalar_prefetch=1, grid=(2,),
+                    in_specs=[pl.BlockSpec((8, 16), lambda i, r: (i, 0))],
+                    out_specs=pl.BlockSpec((8, 16), out_map)),
+                out_shape=jax.ShapeDtypeStruct((16, 16), jnp.float32),
+                compiler_params=pltpu.CompilerParams(
+                    dimension_semantics=("parallel",)),
+                interpret=True,
+            )(rows, x)
+
+        return fn
+
+    @pytest.mark.parametrize("rows_shape,declared,rules", [
+        ((2,), 1, set()),
+        ((2,), 0, {"PL-SMEM-SHAPE"}),
+        ((1, 2), 1, {"PL-SMEM-SHAPE"}),
+    ])
+    def test_scalar_prefetch_count_and_rank(self, rows_shape, declared,
+                                            rules):
+        fn = self._prefetch_kernel(lambda i, r: (i, 0))
+        got = audit_pallas(fn, jnp.zeros(rows_shape, jnp.int32),
+                           jnp.zeros((16, 16), jnp.float32),
+                           what="prefetch kernel", scalar_prefetch=declared)
+        assert _rules(got) == rules
+
+    def test_fires_on_output_index_map_reading_prefetch(self):
+        # zeros for ``rows`` would "prove" (rows[i], 0) disjoint or aliased
+        # on values the kernel never sees: the audit must refuse it
+        fn = self._prefetch_kernel(lambda i, r: (r[i], 0))
+        got = audit_pallas(fn, jnp.zeros((2,), jnp.int32),
+                           jnp.zeros((16, 16), jnp.float32),
+                           what="prefetch kernel", scalar_prefetch=1)
+        assert _rules(got) == {"PL-WRITE-ALIAS"}
+        assert "scalar-prefetch" in got[0].message
+
 
 # ---------------------------------------------------------------------------
 # AST lint on the seeded fixtures

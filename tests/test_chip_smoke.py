@@ -57,8 +57,7 @@ def test_exits_nonzero_alone(tmp_path):
 
 
 def test_rsvd_phase_small():
-    out = _load().rsvd_phase(n=256, rank=16, s_p=1e-4, seed=0,
-                             require_kernels=False)
+    out = _load().rsvd_phase(n=256, rank=16, s_p=1e-4, seed=0)
     assert set(out) == {"f32 bfloat16", "shgemm bfloat16", "shgemm float16",
                         "shgemm_pallas bfloat16", "shgemm_fused bfloat16"}
 
@@ -73,7 +72,7 @@ def test_serve_phase_small():
     cfg = smoke_config(QWEN3_0_6B)
     out = _load().serve_phase(cfg, slots=4, max_seq=64, prompt_len=16,
                               max_new=4, n_requests=4, kv_rank=cfg.head_dim,
-                              seed=0, require_kernels=False)
+                              seed=0)
     assert len(out) == 8
 
 

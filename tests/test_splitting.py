@@ -37,6 +37,23 @@ def test_bf16_split_bits_equal_cast_round_trip():
                                   np.asarray(lo_cast, np.float32))
 
 
+def test_fp16_split_flushes_fp16_subnormals_into_lo():
+    """reduce_precision has no fp16 subnormals: below fp16's smallest normal
+    (2^-14) hi is 0 and lo = fp16(a * 2^11) carries the value, where the
+    paper's cast-based split (and the Pallas kernels) keep a subnormal hi.
+    From 2^-14 up, hi is a's fp16 rounding as before."""
+    tiny = np.array([3e-5, -1e-6, 6.0e-5, -2.0**-15, 1e-7], np.float32)
+    normal = np.array([2.0**-14, -2.0**-14, 1.0], np.float32)
+    hi, lo = splitting.split_fp32_fp16(jnp.asarray(np.concatenate([tiny,
+                                                                   normal])))
+    hi, lo = np.asarray(hi), np.asarray(lo)
+    n = len(tiny)
+    assert not np.any(hi[:n]) and np.all(tiny.astype(np.float16) != 0)
+    np.testing.assert_array_equal(lo[:n], (tiny * 2.0**11).astype(np.float16))
+    np.testing.assert_array_equal(hi[n:], normal.astype(np.float16))
+    np.testing.assert_array_equal(lo[n:], np.zeros(len(normal), np.float16))
+
+
 def test_bf16_split_residual_bound():
     """|a - hi - lo| <= u_bf16^2 * |a| (Eq. 44's A_Delta bound, bf16 form)."""
     a = jnp.asarray(_FIXED)
