@@ -1,0 +1,10 @@
+"""small_svd_ms.rsvd: device time of the operations under the scope
+``rsvd.small_svd`` (line 4 of Algorithm 1, the SVD of B = Q^T A) per
+``rsvd`` call, from a trace of calls at the cell's arguments after the
+window (``chipbench/scopes.py``).  Each operation counts its self time."""
+
+from chipbench import scopes
+
+
+def read(run):
+    return scopes.read_scope(run, "rsvd.small_svd")

@@ -87,13 +87,16 @@ def rp_hosvd(key: jax.Array, a: jax.Array, ranks: tuple[int, ...], *,
         # Omega_(i) out of the hash instead of HBM (it is the *largest*
         # operand here: prod_{k!=i} I_k rows), and dist="khatri_rao" skips
         # the unfolding-width contraction entirely (_mode_sketch).
-        w = _mode_sketch(keys[i], a, i, ranks[i], method=method, dist=dist,
-                         omega_dtype=omega_dtype)
-        q, _ = jnp.linalg.qr(w)                  # line 3
+        with jax.named_scope("hosvd.project"):
+            w = _mode_sketch(keys[i], a, i, ranks[i], method=method,
+                             dist=dist, omega_dtype=omega_dtype)
+        with jax.named_scope("hosvd.factor"):
+            q, _ = jnp.linalg.qr(w)              # line 3
         factors.append(q)
     core = a
     for i, q in enumerate(factors):
-        core = mode_dot(core, q.T, i)            # line 5
+        with jax.named_scope("hosvd.core"):
+            core = mode_dot(core, q.T, i)        # line 5
     return TuckerResult(core, tuple(factors))
 
 
@@ -109,11 +112,14 @@ def rp_sthosvd(key: jax.Array, a: jax.Array, ranks: tuple[int, ...], *,
     keys = jax.random.split(key, a.ndim)
     factors = []
     for i in range(a.ndim):
-        w = _mode_sketch(keys[i], core, i, ranks[i], method=method,
-                         dist=dist, omega_dtype=omega_dtype)
-        q, _ = jnp.linalg.qr(w)
+        with jax.named_scope("hosvd.project"):
+            w = _mode_sketch(keys[i], core, i, ranks[i], method=method,
+                             dist=dist, omega_dtype=omega_dtype)
+        with jax.named_scope("hosvd.factor"):
+            q, _ = jnp.linalg.qr(w)
         factors.append(q)
-        core = mode_dot(core, q.T, i)
+        with jax.named_scope("hosvd.core"):
+            core = mode_dot(core, q.T, i)
     return TuckerResult(core, tuple(factors))
 
 

@@ -89,28 +89,36 @@ def rsvd(key: jax.Array, a: jax.Array, rank: int, *, oversample: int = 10,
     _check_rank(rank, m, n)
     p_hat = min(rank + oversample, min(m, n))
 
+    # Each line runs under a named scope (repro.tracing.SCOPES): metadata
+    # only, so a device trace's operations group by line.
     # Line 1: Y = A . Omega — THE mixed-precision projection.  Key-based:
     # with method="shgemm_fused" Omega is generated inside the kernel and
     # never materialized (zero HBM bytes for the random matrix).
-    y = proj.sketch(key, a, p_hat, method=method, dist=dist,
-                    omega_dtype=omega_dtype)
+    with jax.named_scope("rsvd.sketch"):
+        y = proj.sketch(key, a, p_hat, method=method, dist=dist,
+                        omega_dtype=omega_dtype)
 
     # Power scheme: re-orthonormalize between passes for stability.
-    for _ in range(power_iters):
-        q, _ = jnp.linalg.qr(y)
-        z = _dot(a.T, q)
-        q, _ = jnp.linalg.qr(z)
-        y = _dot(a, q)
+    with jax.named_scope("rsvd.power"):
+        for _ in range(power_iters):
+            q, _ = jnp.linalg.qr(y)
+            z = _dot(a.T, q)
+            q, _ = jnp.linalg.qr(z)
+            y = _dot(a, q)
 
     # Line 2: thin QR.
-    q, _ = jnp.linalg.qr(y)
+    with jax.named_scope("rsvd.qr"):
+        q, _ = jnp.linalg.qr(y)
     # Line 3: B = Q^T A  (p_hat x n).
-    b = _dot(q.T, a)
+    with jax.named_scope("rsvd.project_b"):
+        b = _dot(q.T, a)
     # Line 4: tSVD of the small matrix.
-    u_b, s, vt = jnp.linalg.svd(b, full_matrices=False)
-    # Line 5: U = Q . U'.
-    u = _dot(q, u_b)
-    return SVDResult(u[:, :rank], s[:rank], vt[:rank, :])
+    with jax.named_scope("rsvd.small_svd"):
+        u_b, s, vt = jnp.linalg.svd(b, full_matrices=False)
+    # Line 5: U = Q . U', and the truncation to ``rank``.
+    with jax.named_scope("rsvd.lift_u"):
+        u = _dot(q, u_b)
+        return SVDResult(u[:, :rank], s[:rank], vt[:rank, :])
 
 
 def rsvd_streamed(key: jax.Array, a_blocks, rank: int, *,

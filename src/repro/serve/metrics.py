@@ -11,7 +11,11 @@ across machines — and derives the standard serving SLOs:
 
 plus aggregate throughput (completed output tokens / span), queue-depth and
 concurrency samples, HBM headroom samples (``kv_bytes_report`` dense vs
-compressed), and the reject count from bounded-queue backpressure.
+compressed), the reject count from bounded-queue backpressure, and the
+scheduler's step counters (``repro.tracing.STEP_COUNTERS``): the share of
+tokens through prefill that were catch-up tokens, slots per batched decode
+step and device-to-host reads per step — the operator's view of what a
+profiler trace's step spans carry.
 
 ``accounting()`` is the conservation check CI asserts: every submitted
 request is rejected, completed, or still in flight — zero requests may
@@ -93,6 +97,10 @@ def format_slo_table(summary: dict) -> str:
                                    f"{summary['queue_depth_mean']:.1f}"),
         ("concurrency max / mean", f"{summary['concurrency_max']} / "
                                    f"{summary['concurrency_mean']:.1f}"),
+        ("catch-up share of prefill",
+         f"{100 * summary['catch_up_share']:.1f}%"),
+        ("slots per decode step", f"{summary['slots_per_decode_step']:.2f}"),
+        ("readbacks per step", f"{summary['readbacks_per_step']:.2f}"),
     ]
     if summary.get("hbm"):
         h = summary["hbm"]
@@ -114,6 +122,9 @@ class ServeMetrics:
         self.queue_depth_samples: list[int] = []
         self.concurrency_samples: list[int] = []
         self.hbm_samples: list[dict] = []
+        # step counters, summed over the sampled steps
+        self.prompt_tokens = self.catch_up_tokens = 0
+        self.decode_slots = self.decode_steps = self.readbacks = 0
         self._t0: Optional[float] = None
         self._t_end: float = 0.0
 
@@ -151,7 +162,15 @@ class ServeMetrics:
         self._t_end = max(self._t_end, now)
 
     def sample(self, queue_depth: int, concurrency: int,
-               hbm: Optional[dict] = None) -> None:
+               hbm: Optional[dict] = None, *, prompt_tokens: int = 0,
+               catch_up_tokens: int = 0, decode_slots: int = 0,
+               readbacks: int = 0) -> None:
+        """One step's gauges and counters."""
+        self.prompt_tokens += prompt_tokens
+        self.catch_up_tokens += catch_up_tokens
+        self.decode_slots += decode_slots
+        self.decode_steps += decode_slots > 0
+        self.readbacks += readbacks
         self.queue_depth_samples.append(int(queue_depth))
         self.concurrency_samples.append(int(concurrency))
         if hbm is not None:
@@ -199,6 +218,8 @@ class ServeMetrics:
                 "headroom_bytes": peak["dense_bytes"]
                 - peak["compressed_bytes"],
             }
+        through_prefill = self.prompt_tokens + self.catch_up_tokens
+        steps = len(self.queue_depth_samples)
         return {
             "completed": len(done),
             "output_tokens": out_tokens,
@@ -219,5 +240,11 @@ class ServeMetrics:
                                  / len(self.concurrency_samples))
             if self.concurrency_samples else 0.0,
             "hbm": hbm,
+            "catch_up_share": (self.catch_up_tokens / through_prefill)
+            if through_prefill else 0.0,
+            "slots_per_decode_step":
+                (self.decode_slots / self.decode_steps)
+                if self.decode_steps else 0.0,
+            "readbacks_per_step": (self.readbacks / steps) if steps else 0.0,
             "accounting": self.accounting(expected),
         }

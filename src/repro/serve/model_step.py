@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.configs.base import ModelCfg
 from repro.models import cache as cache_mod
 from repro.models import registry as R
@@ -56,6 +57,7 @@ class ModelStep:
         self.cache = cache_mod.build_cache(cfg, slots, max_seq)
         self.pos = np.zeros(slots, np.int32)       # next write position
         self.last_logits: Optional[jax.Array] = None  # last decode step's
+        self.readbacks = 0       # device-to-host reads made by ``readback``
         self._decode = jax.jit(R.make_serve_step(cfg))
         self._decode_masked = jax.jit(self._make_masked_decode())
         self._prefill_one = jax.jit(self._make_slot_prefill())
@@ -214,19 +216,20 @@ class ModelStep:
         need no zeroing: rows beyond the tenant's pos sit outside the
         causal mask, and factor finalization masks rows the sketch never
         streamed (kv_compress._factor_one)."""
-        self.pos[slot] = 0
-        for path in self._ring_paths:
-            group, i, name = path
-            leaf = self.cache[group][i][name]
-            if group == "scan":
-                self.cache[group][i][name] = leaf.at[:, slot].set(0)
-            else:
-                self.cache[group][i][name] = leaf.at[slot].set(0)
-        if self.kv_sketch_rank:
-            self._reset_slot_sketches(slot)
-            self._kv_pending[slot] = None
-            self._kv_next_row[slot] = 0
-            self._kv_contig[slot] = True
+        with tracing.span("model_step.begin_slot"):
+            self.pos[slot] = 0
+            for path in self._ring_paths:
+                group, i, name = path
+                leaf = self.cache[group][i][name]
+                if group == "scan":
+                    self.cache[group][i][name] = leaf.at[:, slot].set(0)
+                else:
+                    self.cache[group][i][name] = leaf.at[slot].set(0)
+            if self.kv_sketch_rank:
+                self._reset_slot_sketches(slot)
+                self._kv_pending[slot] = None
+                self._kv_next_row[slot] = 0
+                self._kv_contig[slot] = True
 
     def _append_slot_sketches(self, slot: int, start: int,
                               length: int) -> None:
@@ -424,14 +427,15 @@ class ModelStep:
                 f"history has a gap the sketch never streamed — "
                 f"compression requires an append-only contiguous history "
                 f"(DESIGN.md §12.1)")
-        for path in self._kv_swap_paths:
-            f = kv_compress.kv_sketch_factor(
-                self._kv_sketches[slot][path], self._kv_hist(slot, path),
-                self.kv_sketch_rank)
-            self._store_factors(slot, path, f)
-        for path in self._kv_swap_paths:
-            self._zero_dense_prefix(slot, path, pos)
-        self._kv_comp_len[slot] = pos
+        with tracing.span("model_step.compress"):
+            for path in self._kv_swap_paths:
+                f = kv_compress.kv_sketch_factor(
+                    self._kv_sketches[slot][path], self._kv_hist(slot, path),
+                    self.kv_sketch_rank)
+                self._store_factors(slot, path, f)
+            for path in self._kv_swap_paths:
+                self._zero_dense_prefix(slot, path, pos)
+            self._kv_comp_len[slot] = pos
 
     def auto_compress(self, slot: int) -> None:
         """Fire the ``kv_compress_ratio`` trigger if the slot's dense tail
@@ -495,7 +499,7 @@ class ModelStep:
 
         slot_mask_ref = [None]  # closed over; set per call below
 
-        def run(params, cache, tokens, start, slot_mask):
+        def prefill_chunk(params, cache, tokens, start, slot_mask):
             slot_mask_ref[0] = slot_mask
 
             def body(carry, tok_pos):
@@ -520,7 +524,7 @@ class ModelStep:
                 (tokens, start + jnp.arange(tokens.shape[0])))
             return cache, logits
 
-        return run
+        return prefill_chunk
 
     def _make_masked_decode(self):
         """Decode step whose cache writes land only for slots in the mask.
@@ -547,7 +551,7 @@ class ModelStep:
                 return jnp.where(mask.reshape(shape), n, o)
             return jax.tree.map(f, new, old)
 
-        def run(params, batch, slot_mask):
+        def decode_masked(params, batch, slot_mask):
             old = batch["cache"]
             logits, new = serve(params, batch)
             cache = {
@@ -558,7 +562,7 @@ class ModelStep:
             }
             return logits, cache
 
-        return run
+        return decode_masked
 
     def prefill_rows(self, slot: int, tokens, start: int) -> jax.Array:
         """Run ``tokens`` through the masked single-slot prefill, writing
@@ -571,21 +575,22 @@ class ModelStep:
         variant) and with single generated tokens during catch-up decode —
         both write at explicit absolute positions, so a slot driven only
         through this path stays contiguous."""
-        toks = jnp.asarray(tokens, jnp.int32)
-        if toks.ndim != 1 or toks.shape[0] == 0:
-            raise ValueError(f"prefill_rows takes a non-empty 1-D token "
-                             f"chunk, got shape {toks.shape}")
-        if start + toks.shape[0] > self.max_seq:
-            raise ValueError(f"prefill of {toks.shape[0]} rows at {start} "
-                             f"overruns max_seq={self.max_seq}")
-        mask = jnp.zeros(self.slots, bool).at[slot].set(True)
-        self.cache, logits = self._prefill_one(
-            self.params, self.cache, toks, jnp.asarray(start, jnp.int32),
-            mask)
-        self.pos[slot] = start + int(toks.shape[0])
-        if self.kv_sketch_rank:
-            self._note_kv_span(slot, start, int(toks.shape[0]))
-        return logits[slot]
+        with tracing.span("model_step.prefill_rows"):
+            toks = jnp.asarray(tokens, jnp.int32)
+            if toks.ndim != 1 or toks.shape[0] == 0:
+                raise ValueError(f"prefill_rows takes a non-empty 1-D token "
+                                 f"chunk, got shape {toks.shape}")
+            if start + toks.shape[0] > self.max_seq:
+                raise ValueError(f"prefill of {toks.shape[0]} rows at "
+                                 f"{start} overruns max_seq={self.max_seq}")
+            mask = jnp.zeros(self.slots, bool).at[slot].set(True)
+            self.cache, logits = self._prefill_one(
+                self.params, self.cache, toks, jnp.asarray(start, jnp.int32),
+                mask)
+            self.pos[slot] = start + int(toks.shape[0])
+            if self.kv_sketch_rank:
+                self._note_kv_span(slot, start, int(toks.shape[0]))
+            return logits[slot]
 
     def decode_logits(self, tokens: np.ndarray, write_pos: int,
                       slot_mask=None) -> jax.Array:
@@ -597,19 +602,27 @@ class ModelStep:
         slots are live and must note their rows / advance their ``pos``.
         Returns (slots, vocab) f32 logits, device-resident (also kept as
         ``last_logits``)."""
-        batch = {"tokens": jnp.asarray(tokens), "cache": self.cache,
-                 "write_pos": jnp.asarray(write_pos, jnp.int32)}
-        if self.kv_fact is not None:
-            batch["kv_factors"] = self.kv_fact
-            batch["comp_len"] = jnp.asarray(self._kv_comp_len)
-        if slot_mask is None:
-            logits, self.cache = self._decode(self.params, batch)
-        else:
-            logits, self.cache = self._decode_masked(
-                self.params, batch, jnp.asarray(slot_mask))
+        with tracing.span("model_step.decode_logits"):
+            batch = {"tokens": jnp.asarray(tokens), "cache": self.cache,
+                     "write_pos": jnp.asarray(write_pos, jnp.int32)}
+            if self.kv_fact is not None:
+                batch["kv_factors"] = self.kv_fact
+                batch["comp_len"] = jnp.asarray(self._kv_comp_len)
+            if slot_mask is None:
+                logits, self.cache = self._decode(self.params, batch)
+            else:
+                logits, self.cache = self._decode_masked(
+                    self.params, batch, jnp.asarray(slot_mask))
         self.last_logits = logits    # device-resident — consumers (tests,
         # probes) np.asarray it; the hot loop never does
         return logits
+
+    def readback(self, x: jax.Array) -> np.ndarray:
+        """``x`` on the host: the step path's one device-to-host read,
+        counted in ``readbacks`` and spanned ``model_step.readback``."""
+        self.readbacks += 1
+        with tracing.span("model_step.readback"):
+            return np.asarray(x)
 
     def sample(self, logits: jax.Array) -> np.ndarray:
         """(slots, vocab) logits -> (slots,) sampled token ids (greedy at
@@ -619,4 +632,14 @@ class ModelStep:
             nxt = jax.random.categorical(sub, logits / self.temperature)
         else:
             nxt = jnp.argmax(logits, axis=-1)
-        return np.asarray(nxt)
+        return self.readback(nxt)
+
+    def pick(self, logits_row: jax.Array) -> int:
+        """Next token from a single slot's (vocab,) logits — greedy, or
+        temperature-sampled through the sample key (consumed in the same
+        order a decode step would)."""
+        row = self.readback(logits_row)
+        if self.temperature > 0:
+            return int(self.sample(row[None, :].repeat(self.slots,
+                                                       axis=0))[0])
+        return int(row.argmax())

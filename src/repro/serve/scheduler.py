@@ -28,6 +28,11 @@ whole-prompt-at-admit loop with:
   fixed ``StepCostModel``, so latency percentiles from a seeded trace are
   exact across machines (CI asserts them); wall-clock numbers are reported
   separately by the bench as information only.
+- **Spans and counters** (``repro.tracing``): each step, admission, prompt
+  chunk, catch-up token, promotion and batched decode is a span on the
+  profiler's clock, recorded only while a profiler session runs.  Each step
+  counts its prompt tokens, catch-up tokens, decode slots and readbacks; the
+  counts go to the step's span as metadata and to ``ServeMetrics.sample``.
 
 Invariant the whole design hangs on: all slots in the decode set share an
 identical pos (the clock) forever — each batched step writes at the common
@@ -41,11 +46,13 @@ would zero dense rows that subsequent chunks still attend).
 from __future__ import annotations
 
 import dataclasses
+import time
 from collections import deque
 from typing import Optional
 
 import numpy as np
 
+from repro import tracing
 from repro.models import cache as cache_mod
 from repro.serve import loadgen
 from repro.serve.metrics import ServeMetrics
@@ -104,6 +111,7 @@ class ScheduledRequest:
     prefilled: int = 0            # prompt tokens written so far
     done: bool = False
     evicted: bool = False
+    submitted_at: float = 0.0     # host clock (time.perf_counter) at submit
 
 
 class Scheduler:
@@ -171,7 +179,8 @@ class Scheduler:
             self.metrics.on_reject(rid, self.clock.now, len(self.queue))
             return False
         self.queue.append(ScheduledRequest(rid=rid, prompt=list(prompt),
-                                           max_new=max_new))
+                                           max_new=max_new,
+                                           submitted_at=time.perf_counter()))
         self.metrics.on_submit(rid, self.clock.now, len(prompt), max_new)
         return True
 
@@ -213,59 +222,59 @@ class Scheduler:
             slot = next(s for s in range(self.model.slots)
                         if self.active[s] is None)
             req = self.queue.popleft()
-            req.slot = slot
-            self.active[slot] = req
-            self.model.begin_slot(slot)   # complete reset: no prior tenant
-            self.metrics.on_admit(req.rid, self.clock.now)
+            with tracing.span("scheduler.admit", rid=req.rid,
+                              slot=slot) as span:
+                if tracing.enabled():
+                    span.set_metadata(queue_wait_ms=1e3 * (
+                        time.perf_counter() - req.submitted_at))
+                req.slot = slot
+                self.active[slot] = req
+                self.model.begin_slot(slot)   # complete reset: no prior tenant
+                self.metrics.on_admit(req.rid, self.clock.now)
 
     # -- the step ----------------------------------------------------------
-    def _prefill_work(self) -> tuple[int, int]:
+    def _prefill_work(self) -> tuple[int, int, int]:
         """Spend up to ``prefill_chunk`` tokens on slots still prefilling or
-        catching up; returns (tokens written, dispatches made)."""
+        catching up; returns (prompt tokens written, catch-up tokens
+        written, dispatches made)."""
         budget = self.prefill_chunk
-        tokens = calls = 0
+        prompt = catch_up = calls = 0
         for slot in self._live():
             if budget <= 0:
                 break
             req = self.active[slot]
             if req.phase == PREFILL:
                 take = min(budget, len(req.prompt) - req.prefilled)
-                logits = self.model.prefill_rows(
-                    slot, req.prompt[req.prefilled:req.prefilled + take],
-                    req.prefilled)
-                req.prefilled += take
-                budget -= take
-                tokens += take
-                calls += 1
-                if req.prefilled == len(req.prompt):
-                    req.phase = READY
-                    # first output token comes from the prefill logits
-                    if not self._emit(slot, self._pick(logits)):
-                        pass
+                with tracing.span("scheduler.prefill", rid=req.rid,
+                                  slot=slot, tokens=take):
+                    logits = self.model.prefill_rows(
+                        slot, req.prompt[req.prefilled:req.prefilled + take],
+                        req.prefilled)
+                    req.prefilled += take
+                    budget -= take
+                    prompt += take
+                    calls += 1
+                    if req.prefilled == len(req.prompt):
+                        req.phase = READY
+                        # first output token comes from the prefill logits
+                        self._emit(slot, self.model.pick(logits))
             req = self.active[slot]
             if req is not None and req.phase == READY:
                 # catch-up: real output tokens at the slot's own positions
                 # until it reaches the decode clock
                 while (budget > 0 and self._decode_clock is not None
                        and int(self.model.pos[slot]) < self._decode_clock):
-                    logits = self.model.prefill_rows(
-                        slot, [req.out[-1]], int(self.model.pos[slot]))
-                    budget -= 1
-                    tokens += 1
-                    calls += 1
-                    if self._emit(slot, self._pick(logits)):
+                    with tracing.span("scheduler.catch_up", rid=req.rid,
+                                      slot=slot):
+                        logits = self.model.prefill_rows(
+                            slot, [req.out[-1]], int(self.model.pos[slot]))
+                        budget -= 1
+                        catch_up += 1
+                        calls += 1
+                        finished = self._emit(slot, self.model.pick(logits))
+                    if finished:
                         break
-        return tokens, calls
-
-    def _pick(self, logits_row) -> int:
-        """Next token from a single slot's (vocab,) logits — greedy, or
-        temperature-sampled through the model's sample key (consumed in the
-        same order a decode step would)."""
-        if self.model.temperature > 0:
-            row = np.asarray(logits_row)[None, :].repeat(self.model.slots,
-                                                         axis=0)
-            return int(self.model.sample(row)[0])
-        return int(np.asarray(logits_row).argmax())
+        return prompt, catch_up, calls
 
     def _promote(self) -> None:
         """Move READY slots whose pos matches the clock into the decode
@@ -277,13 +286,14 @@ class Scheduler:
         ready = [s for s in self._live() if self.active[s].phase == READY]
         if not ready:
             return
-        if self._decode_clock is None:
-            seed = max(ready, key=lambda s: int(self.model.pos[s]))
-            self._decode_clock = int(self.model.pos[seed])
-        for s in ready:
-            if int(self.model.pos[s]) == self._decode_clock:
-                self.active[s].phase = DECODE
-                self.model.auto_compress(s)
+        with tracing.span("scheduler.promote"):
+            if self._decode_clock is None:
+                seed = max(ready, key=lambda s: int(self.model.pos[s]))
+                self._decode_clock = int(self.model.pos[seed])
+            for s in ready:
+                if int(self.model.pos[s]) == self._decode_clock:
+                    self.active[s].phase = DECODE
+                    self.model.auto_compress(s)
 
     def _decode_step(self) -> int:
         """One batched decode for the decode set at the shared clock; cache
@@ -292,47 +302,56 @@ class Scheduler:
         dec = self._decoding()
         if not dec:
             return 0
-        clock = self._decode_clock
-        tokens = np.zeros((self.model.slots, 1), np.int32)
-        mask = np.zeros(self.model.slots, bool)
-        for s in dec:
-            req = self.active[s]
-            tokens[s, 0] = req.out[-1] if req.out else req.prompt[-1]
-            mask[s] = True
-        logits = self.model.decode_logits(tokens, clock, slot_mask=mask)
-        nxt = self.model.sample(logits)
-        if self.model.kv_sketch_rank:
+        with tracing.span("scheduler.decode", slots=len(dec)):
+            clock = self._decode_clock
+            tokens = np.zeros((self.model.slots, 1), np.int32)
+            mask = np.zeros(self.model.slots, bool)
             for s in dec:
-                self.model._note_kv_row(s, clock)
-        for s in dec:
-            self.model.pos[s] = clock + 1
-            if not self._emit(s, nxt[s]) and self.model.kv_sketch_rank:
-                self.model.auto_compress(s)
-        if self._decoding():
-            self._decode_clock = clock + 1
+                req = self.active[s]
+                tokens[s, 0] = req.out[-1] if req.out else req.prompt[-1]
+                mask[s] = True
+            logits = self.model.decode_logits(tokens, clock, slot_mask=mask)
+            nxt = self.model.sample(logits)
+            if self.model.kv_sketch_rank:
+                for s in dec:
+                    self.model._note_kv_row(s, clock)
+            for s in dec:
+                self.model.pos[s] = clock + 1
+                if not self._emit(s, nxt[s]) and self.model.kv_sketch_rank:
+                    self.model.auto_compress(s)
+            if self._decoding():
+                self._decode_clock = clock + 1
         return len(dec)
 
     def step(self) -> bool:
         """One scheduler step: admit, spend the prefill/catch-up token
         budget, promote, run one batched decode, advance virtual time by
-        the step's modeled cost, sample the gauges.  Returns True if any
-        work happened."""
-        self._admit()
-        p_tokens, p_calls = self._prefill_work()
-        self._promote()
-        n_dec = self._decode_step()
-        if p_tokens == 0 and n_dec == 0:
-            return False
-        cost_us = (p_calls * self.cost.prefill_base_us
-                   + p_tokens * self.cost.prefill_per_token_us)
-        if n_dec:
-            cost_us += (self.cost.decode_base_us
-                        + n_dec * self.cost.decode_per_token_us)
-        self.clock.advance(cost_us * 1e-6)
-        self.metrics.sample(len(self.queue), len(self._live()),
-                            self.model.kv_bytes_report()
-                            if self.model.kv_sketch_rank else None)
-        return True
+        the step's modeled cost, sample the gauges and the step's counters.
+        Returns True if any work happened."""
+        with tracing.span("scheduler.step") as span:
+            reads = self.model.readbacks
+            self._admit()
+            prompt, catch_up, calls = self._prefill_work()
+            self._promote()
+            n_dec = self._decode_step()
+            counts = {"prompt_tokens": prompt, "catch_up_tokens": catch_up,
+                      "decode_slots": n_dec,
+                      "readbacks": self.model.readbacks - reads}
+            if tracing.enabled():
+                span.set_metadata(**counts)
+            if prompt + catch_up == 0 and n_dec == 0:
+                return False
+            cost_us = (calls * self.cost.prefill_base_us
+                       + (prompt + catch_up) * self.cost.prefill_per_token_us)
+            if n_dec:
+                cost_us += (self.cost.decode_base_us
+                            + n_dec * self.cost.decode_per_token_us)
+            self.clock.advance(cost_us * 1e-6)
+            self.metrics.sample(len(self.queue), len(self._live()),
+                                self.model.kv_bytes_report()
+                                if self.model.kv_sketch_rank else None,
+                                **counts)
+            return True
 
     def run(self, trace: list[loadgen.TraceRequest]) -> ServeMetrics:
         """Replay a load trace on the virtual clock: deliver arrivals as
