@@ -34,7 +34,7 @@ _RG_C = 8.0  # Griffin's fixed recurrence sharpness
 
 def causal_conv1d(x, w, conv_state=None):
     """x: (B,S,C); w: (W,C) depthwise.  conv_state: (B,W-1,C) previous inputs
-    (decode).  Returns (y, new_state)."""
+    (decode).  Returns (y, new_state); a given state keeps its dtype."""
     width = w.shape[0]
     if conv_state is None:
         pad = jnp.zeros((x.shape[0], width - 1, x.shape[2]), x.dtype)
@@ -43,6 +43,8 @@ def causal_conv1d(x, w, conv_state=None):
     xp = jnp.concatenate([pad, x], axis=1)          # (B, S+W-1, C)
     y = sum(xp[:, i:i + x.shape[1]] * w[i][None, None] for i in range(width))
     new_state = xp[:, -(width - 1):]
+    if conv_state is not None:
+        new_state = new_state.astype(conv_state.dtype)
     return y, new_state
 
 

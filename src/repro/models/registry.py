@@ -156,14 +156,17 @@ def make_serve_step(cfg: ModelCfg) -> Callable:
 
     Optional batch keys ``kv_factors``/``comp_len`` carry the serving
     engine's compressed-prefix state (serve/kv_compress.py, DESIGN.md §12);
-    they ride through read-only — the returned cache never contains them."""
+    they ride through read-only — the returned cache never contains them.
+    ``cache_slot`` runs the tokens on one slot of the pool and returns only
+    the new rows (``transformer.forward``)."""
 
     def step(params, batch):
         p = T.cast_params_for_compute(cfg, params)
         out = T.forward(cfg, p, batch["tokens"], cache=batch["cache"],
                         write_pos=batch["write_pos"],
                         kv_factors=batch.get("kv_factors"),
-                        comp_len=batch.get("comp_len"))
+                        comp_len=batch.get("comp_len"),
+                        cache_slot=batch.get("cache_slot"))
         return _final_logits(cfg, out.logits[:, -1]), out.cache
 
     return step

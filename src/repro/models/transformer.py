@@ -240,11 +240,12 @@ def _act_dtype(cfg):
 
 def apply_layer(cfg: ModelCfg, spec: LayerSpec, p: dict, x: jax.Array, *,
                 positions, cache, write_pos, enc_out, return_cache: bool,
-                causal: bool = True, factors=None, comp_len=None):
+                causal: bool = True, factors=None, comp_len=None,
+                cache_slot=None):
     """Residual block: norm -> mixer -> (+) [norm -> ffn -> (+)].
     Returns (x, new_cache_dict_or_None).  ``factors``/``comp_len`` carry the
     serving engine's compressed-prefix state (DESIGN.md §12) — None/empty
-    for every non-serving path."""
+    for every non-serving path; ``cache_slot``: see ``forward``."""
     x = constrain(x, "batch", None, None)   # re-anchor the residual stream
     h = L.apply_norm(cfg, p, "norm1", x)
     new_cache: dict[str, Any] = {}
@@ -256,9 +257,13 @@ def apply_layer(cfg: ModelCfg, spec: LayerSpec, p: dict, x: jax.Array, *,
         mix, kv = _attn_with_cache(cfg, spec, p, h, positions=positions,
                                    cache=c, write_pos=write_pos,
                                    return_cache=return_cache, causal=causal,
-                                   factors=factors, comp_len=comp_len)
+                                   factors=factors, comp_len=comp_len,
+                                   cache_slot=cache_slot)
         if kv is not None:
             new_cache.update({"k": kv.k, "v": kv.v})
+    elif cache_slot is not None:
+        raise ValueError(f"a pool slot's rows are attention rows, not the "
+                         f"{spec.mixer!r} mixer's state")
     elif spec.mixer == "mla":
         mix, c = mla_mod.mla_block(cfg, p, h, positions=positions,
                                    cache=cache if cache and "ckv" in cache else None,
@@ -324,8 +329,11 @@ def apply_layer(cfg: ModelCfg, spec: LayerSpec, p: dict, x: jax.Array, *,
 
 
 def _attn_with_cache(cfg, spec, p, h, *, positions, cache, write_pos,
-                     return_cache, causal, factors=None, comp_len=None):
-    """attn_block + prefill cache construction + non-causal (encoder) path."""
+                     return_cache, causal, factors=None, comp_len=None,
+                     cache_slot=None):
+    """attn_block + prefill cache construction + non-causal (encoder) path.
+    With ``cache_slot`` the cache is the slot pool and the returned one the
+    S new rows alone (see ``forward``)."""
     dt = h.dtype
     scale = cfg.query_scale or (1.0 / math.sqrt(cfg.head_dim))
     q, k, v = L.qkv_project(cfg, p, "attn", h)
@@ -361,6 +369,11 @@ def _attn_with_cache(cfg, spec, p, h, *, positions, cache, write_pos,
         # cache per layer (30 GB/token on qwen3 decode_32k — §Perf iter 13).
         s_kv = cache.k.shape[1]
         if spec.window is not None and s_kv <= spec.window:
+            if q.shape[1] != 1 or cache_slot is not None:
+                raise ValueError(f"a ring-buffer cache (window "
+                                 f"{spec.window}, {s_kv} rows) takes one "
+                                 f"token per call of its own batch, not "
+                                 f"{q.shape[1]} or a pool slot")
             # ring buffer: slot i holds absolute position
             # write_pos - ((wp - i) mod s_kv)
             wp = jnp.mod(write_pos, s_kv)
@@ -368,11 +381,23 @@ def _attn_with_cache(cfg, spec, p, h, *, positions, cache, write_pos,
         else:
             wp = write_pos
             kv_pos = jnp.arange(s_kv)
-        kv = L.KVCache(
-            jax.lax.dynamic_update_slice_in_dim(
-                cache.k, k.astype(cache.k.dtype), wp, axis=1),
-            jax.lax.dynamic_update_slice_in_dim(
-                cache.v, v.astype(cache.v.dtype), wp, axis=1))
+        if cache_slot is None:
+            kv = L.KVCache(
+                jax.lax.dynamic_update_slice_in_dim(
+                    cache.k, k.astype(cache.k.dtype), wp, axis=1),
+                jax.lax.dynamic_update_slice_in_dim(
+                    cache.v, v.astype(cache.v.dtype), wp, axis=1))
+            ak, av = kv.k, kv.v
+        else:
+            # one slot of the pool: attend over its rows with the chunk
+            # written in, and hand back the chunk's rows alone, so that no
+            # whole slot is copied out of the pool or back into it
+            def slot_rows(c, new):
+                rows = jax.lax.dynamic_slice_in_dim(c, cache_slot, 1, axis=0)
+                return jax.lax.dynamic_update_slice_in_dim(rows, new, wp,
+                                                           axis=1)
+            kv = L.KVCache(k.astype(cache.k.dtype), v.astype(cache.v.dtype))
+            ak, av = slot_rows(cache.k, kv.k), slot_rows(cache.v, kv.v)
         if factors and comp_len is not None and q.shape[1] == 1:
             # compressed-prefix decode (DESIGN.md §12): rows [0, comp_len_b)
             # of this cache live only as rank-r factors; the dense rows
@@ -385,16 +410,16 @@ def _attn_with_cache(cfg, spec, p, h, *, positions, cache, write_pos,
                 # path below is its reference oracle (DESIGN.md §16)
                 from repro.kernels import ops as kops
                 out = kops.factored_decode_attention(
-                    q, kv.k, kv.v, factors["k_us"], factors["k_vt"],
+                    q, ak, av, factors["k_us"], factors["k_vt"],
                     factors["v_us"], factors["v_vt"], comp_len, write_pos,
                     scale=scale, cap=cfg.attn_softcap)
             else:
                 out = L.factored_decode_attention(
-                    q, kv.k, kv.v, factors["k_us"], factors["k_vt"],
+                    q, ak, av, factors["k_us"], factors["k_vt"],
                     factors["v_us"], factors["v_vt"], comp_len,
                     write_pos=write_pos, scale=scale, cap=cfg.attn_softcap)
         else:
-            out = L.attention(q, kv.k.astype(dt), kv.v.astype(dt),
+            out = L.attention(q, ak.astype(dt), av.astype(dt),
                               causal=causal, window=spec.window, scale=scale,
                               cap=cfg.attn_softcap,
                               q_positions=positions.reshape(-1),
@@ -414,7 +439,7 @@ def apply_stack(cfg: ModelCfg, params: dict, x: jax.Array, *, positions,
                 cache, write_pos, enc_out, return_cache: bool,
                 causal: bool = True, pattern=None, prefix="layers",
                 n_periods=None, n_rem=None, use_prelude: bool = True,
-                kv_factors=None, comp_len=None):
+                kv_factors=None, comp_len=None, cache_slot=None):
     """Scanned pattern group + remainder layers."""
     pattern = pattern or cfg.pattern
     n_periods = cfg.n_scan_periods if n_periods is None else n_periods
@@ -437,7 +462,8 @@ def apply_stack(cfg: ModelCfg, params: dict, x: jax.Array, *, positions,
                             positions=positions, cache=cj,
                             write_pos=write_pos, enc_out=enc_out,
                             return_cache=return_cache, causal=causal,
-                            factors=fj, comp_len=comp_len)
+                            factors=fj, comp_len=comp_len,
+                            cache_slot=cache_slot)
         new_pre.append(nc if nc is not None else {})
 
     def period_body(x, p_i, c_i, f_i=None):
@@ -449,7 +475,8 @@ def apply_stack(cfg: ModelCfg, params: dict, x: jax.Array, *, positions,
                                 positions=positions, cache=ci,
                                 write_pos=write_pos, enc_out=enc_out,
                                 return_cache=return_cache, causal=causal,
-                                factors=fi, comp_len=comp_len)
+                                factors=fi, comp_len=comp_len,
+                                cache_slot=cache_slot)
             new_cs.append(nc if nc is not None else {})
         return x, tuple(new_cs)
 
@@ -497,7 +524,8 @@ def apply_stack(cfg: ModelCfg, params: dict, x: jax.Array, *, positions,
                             positions=positions, cache=cj,
                             write_pos=write_pos, enc_out=enc_out,
                             return_cache=return_cache, causal=causal,
-                            factors=fj, comp_len=comp_len)
+                            factors=fj, comp_len=comp_len,
+                            cache_slot=cache_slot)
         new_rem.append(nc if nc is not None else {})
 
     new_cache = None
@@ -584,8 +612,17 @@ def forward(cfg: ModelCfg, params: dict, tokens: jax.Array, *,
             enc_embeds: Optional[jax.Array] = None,
             return_cache: bool = False,
             kv_factors: Optional[dict] = None,
-            comp_len: Optional[jax.Array] = None) -> ForwardOut:
-    """tokens: (B, S).  Decode: S == 1 with a populated cache.
+            comp_len: Optional[jax.Array] = None,
+            cache_slot: Optional[jax.Array] = None) -> ForwardOut:
+    """tokens: (B, S).  Decode: S == 1 with a populated cache; a cached
+    call of S > 1 tokens (single-slot chunked prefill) sits at positions
+    ``write_pos + arange(S)`` and attends causally over the cache.
+
+    ``cache_slot`` (serving prefill; attention over plain rows only): the
+    cache is the whole slot pool, ``tokens`` (1, S) belong to its slot
+    ``cache_slot``, attention reads that slot's rows alone, and the
+    returned cache holds each layer's S new rows, (1, S, ...) in place of
+    the pool, for the caller to write at ``(cache_slot, write_pos)``.
 
     ``kv_factors``/``comp_len`` (serving only, DESIGN.md §12): a
     ``cache.build_kv_factors`` pytree of per-layer rank-r KV factors plus the
@@ -599,8 +636,9 @@ def forward(cfg: ModelCfg, params: dict, tokens: jax.Array, *,
         img = jnp.dot(img_embeds.astype(dt), params["vlm/proj"].astype(dt))
         x = jnp.concatenate([img, x], axis=1)
 
-    if cache is not None and tokens.shape[1] == 1:
-        positions = jnp.asarray(write_pos).reshape(1)
+    if cache is not None:
+        # a cached call of S tokens writes and attends at write_pos + i
+        positions = jnp.asarray(write_pos) + jnp.arange(x.shape[1])
     else:
         positions = jnp.arange(x.shape[1])
 
@@ -615,7 +653,8 @@ def forward(cfg: ModelCfg, params: dict, tokens: jax.Array, *,
     x, new_cache = apply_stack(cfg, params, x, positions=positions,
                                cache=cache, write_pos=write_pos,
                                enc_out=enc_out, return_cache=return_cache,
-                               kv_factors=kv_factors, comp_len=comp_len)
+                               kv_factors=kv_factors, comp_len=comp_len,
+                               cache_slot=cache_slot)
 
     x = L.apply_norm(cfg, params, "final_norm", x)
     if cfg.tie_embeddings:

@@ -6,7 +6,7 @@ next queue entry.  All jit'd shapes are static: (slots, max_seq).
 
 This module is now the thin request-lifecycle facade over the model-step
 layer (serve/model_step.py): ``Engine`` inherits every tensor primitive —
-masked slot prefill, batched decode, the incremental per-slot streaming
+single-slot prefill, batched decode, the incremental per-slot streaming
 sketches (repro.stream; bit-identical to a full recompute over the same
 appended rows, DESIGN.md §10/§12), rolling sketches for sliding-window
 layers, FactoredKV swaps and the ``kv_slot_bytes``/``kv_bytes_report`` HBM
@@ -78,13 +78,10 @@ class Engine(ModelStep):
             if self.active[s] is None and self.queue:
                 req = self.queue.pop(0)
                 self.active[s] = req
-                toks = jnp.asarray(req.prompt, jnp.int32)
-                mask = jnp.zeros(self.slots, bool).at[s].set(True)
-                self.cache, logits = self._prefill_one(
-                    self.params, self.cache, toks,
-                    jnp.asarray(0, jnp.int32), mask)
+                logits = self._prefill(s, np.asarray(req.prompt, np.int32),
+                                       0)
                 self.pos[s] = len(req.prompt)
-                nxt = int(jnp.argmax(logits[s]))
+                nxt = int(jnp.argmax(logits))
                 req.out.append(nxt)
                 if self.kv_sketch_rank:
                     self._reset_slot_sketches(s)

@@ -5,7 +5,7 @@ This is the bottom half of the old monolithic ``serve/engine.py`` split
 (DESIGN.md §15): everything that touches params, the KV cache, the
 incremental per-slot sketches (serve/kv_compress.py, DESIGN.md §10/§12) and
 the factored leaves lives here, as methods that transform the slot pool —
-``prefill_rows`` (masked single-slot chunk at explicit positions),
+``prefill_rows`` (single-slot chunk at explicit positions, pool donated),
 ``decode_logits``/``sample`` (one batched decode step at the uniform slot
 clock), ``compress_slot``/``auto_compress`` (dense-prefix -> FactoredKV
 swaps), ``begin_slot`` (complete per-slot reset for a new tenant) and the
@@ -26,6 +26,7 @@ non-contiguity guard and serve dense (DESIGN.md §12.1).
 
 from __future__ import annotations
 
+import dataclasses
 import math
 from typing import Optional
 
@@ -38,6 +39,77 @@ from repro.configs.base import ModelCfg
 from repro.models import cache as cache_mod
 from repro.models import registry as R
 from repro.serve import kv_compress
+
+
+def chunk_prefill(cfg: ModelCfg) -> bool:
+    """Whether a prefill chunk runs as one batch-1 forward of all its
+    tokens: every layer is self-attention over plain rows, one per
+    position.  A windowed layer's cache is a ring buffer whatever the window
+    (``models/cache``), and a ring, recurrent state, MLA latents and
+    cross-attention take the chunk one token at a time instead."""
+    return cfg.encdec is None and all(
+        s.mixer == "attn" and not s.cross_attn and s.window is None
+        for s in cfg.prelude + cfg.pattern)
+
+
+def make_prefill_chunk(cfg: ModelCfg):
+    """The jitted ``prefill_chunk(params, cache, tokens, start, slot) ->
+    (cache, logits)`` and its path, ``"chunk"`` or ``"serial"``.  The program
+    puts ``tokens`` (S,) at absolute positions ``start + arange(S)`` of pool
+    slot ``slot`` (both int32 scalars, traced), returning the pool with that
+    slot's new rows written and the (vocab,) f32 logits after the last
+    token.  Only the slot's rows are read or written, batch-1, and the new
+    rows are put back into the pool, which is donated, so they land in
+    place.
+
+    Under ``chunk_prefill`` the S tokens are one causal forward over the
+    pool that reads the slot's rows layer by layer and returns only the S
+    new rows; otherwise a token-serial scan runs the slot's rows, sliced
+    out, and the whole slot goes back (ring, recurrent and latent state is
+    not a row range).  A routed expert takes every token of the chunk that
+    chose it, as it did when the chunk went one token at a time: the
+    capacity factor is raised to the expert count, past any chunk's load."""
+    if cfg.moe is not None:
+        cfg = cfg.with_(moe=dataclasses.replace(
+            cfg.moe, capacity_factor=float(cfg.moe.num_experts)))
+    serve = R.make_serve_step(cfg)
+    chunked = chunk_prefill(cfg)
+    # the slot axis of each cache group: scan-stacked leaves lead with periods
+    axes = {"pre": 0, "scan": 1, "rem": 0}
+
+    def per_leaf(f, *trees):
+        return {g: jax.tree.map(lambda *xs, ax=ax: f(ax, *xs),
+                                *(t[g] for t in trees))
+                for g, ax in axes.items()}
+
+    def prefill_chunk(params, cache, tokens, start, slot):
+        if chunked:
+            logits, new = serve(params, {"tokens": tokens[None],
+                                         "cache": cache, "write_pos": start,
+                                         "cache_slot": slot})
+            at_row = start
+        else:
+            rows = per_leaf(lambda ax, x: jax.lax.dynamic_slice_in_dim(
+                x, slot, 1, ax), cache)
+
+            def body(rows, tok_pos):
+                tok, pos = tok_pos
+                logits, rows = serve(params, {"tokens": tok.reshape(1, 1),
+                                              "cache": rows,
+                                              "write_pos": pos})
+                return rows, logits
+            new, logits = jax.lax.scan(
+                body, rows, (tokens, start + jnp.arange(tokens.shape[0])))
+            logits, at_row = logits[-1], 0
+
+        def put(ax, pool, x):
+            at = [0] * pool.ndim
+            at[ax], at[ax + 1] = slot, at_row
+            return jax.lax.dynamic_update_slice(pool, x, at)
+        return per_leaf(put, cache, new), logits[0]
+
+    return (jax.jit(prefill_chunk, donate_argnums=1),
+            "chunk" if chunked else "serial")
 
 
 class ModelStep:
@@ -60,7 +132,8 @@ class ModelStep:
         self.readbacks = 0       # device-to-host reads made by ``readback``
         self._decode = jax.jit(R.make_serve_step(cfg))
         self._decode_masked = jax.jit(self._make_masked_decode())
-        self._prefill_one = jax.jit(self._make_slot_prefill())
+        self._prefill_one, self._prefill_path = make_prefill_chunk(cfg)
+        self.prefill_calls = {"chunk": 0, "serial": 0}
         # incremental KV compression (serve/kv_compress.py): per-slot,
         # per-cache-leaf streaming sketch states, appended as tokens land.
         self.kv_sketch_rank = kv_sketch_rank
@@ -483,49 +556,6 @@ class ModelStep:
                                     for r in per_slot),
         }
 
-    # -- slot prefill: run tokens through masked decode steps (static-shaped;
-    #    the scheduler chunks calls to bound compile variants) ---------------
-    def _make_slot_prefill(self):
-        serve = R.make_serve_step(self.cfg)
-
-        def mask_group(new, old, axis):
-            def f(n, o):
-                if n is None:
-                    return None
-                shape = [1] * n.ndim
-                shape[axis] = self.slots
-                return jnp.where(slot_mask_ref[0].reshape(shape), n, o)
-            return jax.tree.map(f, new, old)
-
-        slot_mask_ref = [None]  # closed over; set per call below
-
-        def prefill_chunk(params, cache, tokens, start, slot_mask):
-            slot_mask_ref[0] = slot_mask
-
-            def body(carry, tok_pos):
-                cache, _ = carry
-                tok, pos = tok_pos
-                logits, new_cache = serve(params, {
-                    "tokens": jnp.broadcast_to(tok, (self.slots, 1)),
-                    "cache": cache, "write_pos": pos})
-                # only the target slot's cache rows advance.  Slot axis: 0 for
-                # pre/rem leaves, 1 for scan-stacked leaves (periods lead).
-                cache = {
-                    "pre": mask_group(new_cache["pre"], cache["pre"], 0),
-                    "scan": (mask_group(new_cache["scan"], cache["scan"], 1)
-                             if cache["scan"] is not None else None),
-                    "rem": mask_group(new_cache["rem"], cache["rem"], 0),
-                }
-                return (cache, logits), None
-
-            zeros = jnp.zeros((self.slots, self.cfg.vocab), jnp.float32)
-            (cache, logits), _ = jax.lax.scan(
-                body, (cache, zeros),
-                (tokens, start + jnp.arange(tokens.shape[0])))
-            return cache, logits
-
-        return prefill_chunk
-
     def _make_masked_decode(self):
         """Decode step whose cache writes land only for slots in the mask.
 
@@ -565,32 +595,53 @@ class ModelStep:
         return decode_masked
 
     def prefill_rows(self, slot: int, tokens, start: int) -> jax.Array:
-        """Run ``tokens`` through the masked single-slot prefill, writing
-        cache rows [start, start + len(tokens)) for ``slot`` only, and
-        return the (vocab,) logits row after the last token.  Advances the
-        slot's ``pos`` and notes the rows with the sketch bookkeeping.
+        """Run ``tokens`` through the single-slot prefill, writing cache rows
+        [start, start + len(tokens)) for ``slot`` only, and return the
+        (vocab,) logits row after the last token.  Advances the slot's
+        ``pos`` and notes the rows with the sketch bookkeeping.
 
         This is the chunked-prefill primitive: the scheduler calls it with
-        bounded-length chunks (each distinct length compiles one scan
-        variant) and with single generated tokens during catch-up decode —
-        both write at explicit absolute positions, so a slot driven only
-        through this path stays contiguous."""
-        with tracing.span("model_step.prefill_rows"):
-            toks = jnp.asarray(tokens, jnp.int32)
-            if toks.ndim != 1 or toks.shape[0] == 0:
-                raise ValueError(f"prefill_rows takes a non-empty 1-D token "
-                                 f"chunk, got shape {toks.shape}")
-            if start + toks.shape[0] > self.max_seq:
-                raise ValueError(f"prefill of {toks.shape[0]} rows at "
-                                 f"{start} overruns max_seq={self.max_seq}")
-            mask = jnp.zeros(self.slots, bool).at[slot].set(True)
-            self.cache, logits = self._prefill_one(
-                self.params, self.cache, toks, jnp.asarray(start, jnp.int32),
-                mask)
+        bounded-length chunks (each distinct length compiles one program,
+        whatever the slot and start) and with single generated tokens
+        during catch-up decode — both write at explicit absolute positions,
+        so a slot driven only through this path stays contiguous.  The span
+        carries ``path``: ``chunk`` (one forward of the chunk) or ``serial``
+        (a token loop, for stacks ``chunk_prefill`` refuses)."""
+        with tracing.span("model_step.prefill_rows",
+                          path=self._prefill_path):
+            toks = np.asarray(tokens, np.int32)
+            logits = self._prefill(slot, toks, start)
             self.pos[slot] = start + int(toks.shape[0])
             if self.kv_sketch_rank:
                 self._note_kv_span(slot, start, int(toks.shape[0]))
-            return logits[slot]
+            return logits
+
+    def _prefill(self, slot: int, toks: np.ndarray, start: int) -> jax.Array:
+        """One call of the prefill program on the donated pool, counted in
+        ``prefill_calls`` by its path; it returns once the program has run.
+        The host arrays go to the device with the call itself, not as
+        programs of their own.
+
+        A slot or start outside the pool is refused here: the program would
+        clamp it onto rows it was not given.  The wait keeps the program in
+        its caller's span: the TPU runtime enqueues a program from its own
+        thread after the call returns, so a profile would otherwise give the
+        program's device time to whatever the host does next.  It costs
+        little, since the scheduler reads back the logits of every call but
+        a prompt's non-final chunks at once."""
+        if toks.ndim != 1 or toks.shape[0] == 0:
+            raise ValueError(f"prefill_rows takes a non-empty 1-D token "
+                             f"chunk, got shape {toks.shape}")
+        if not 0 <= slot < self.slots:
+            raise ValueError(f"slot {slot} is not in the pool of "
+                             f"{self.slots}")
+        if start < 0 or start + toks.shape[0] > self.max_seq:
+            raise ValueError(f"prefill of {toks.shape[0]} rows at "
+                             f"{start} overruns max_seq={self.max_seq}")
+        self.prefill_calls[self._prefill_path] += 1
+        self.cache, logits = self._prefill_one(
+            self.params, self.cache, toks, np.int32(start), np.int32(slot))
+        return logits.block_until_ready()
 
     def decode_logits(self, tokens: np.ndarray, write_pos: int,
                       slot_mask=None) -> jax.Array:

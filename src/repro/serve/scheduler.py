@@ -16,7 +16,7 @@ whole-prompt-at-admit loop with:
   step), so a freshly prefilled slot whose pos trails the clock would go
   non-contiguous — the exact gap that forbids compression (DESIGN.md
   §12.1).  Instead the scheduler generates that slot's real output tokens
-  one at a time at its OWN positions (masked single-slot steps) until its
+  one at a time at its OWN positions (single-slot prefill calls) until its
   pos equals the clock, then promotes it into the batched decode set.
   Every scheduler-managed slot therefore keeps an append-only contiguous
   history and stays compressible under churn.
@@ -39,7 +39,7 @@ identical pos (the clock) forever — each batched step writes at the common
 clock and advances every member by one, members only join at pos == clock,
 and when the set drains the largest-pos ready slot re-seeds the clock.
 Compression fires only at promotion and after batched decode tokens, never
-mid-prefill/catch-up (the masked prefill step is not factor-aware: a swap
+mid-prefill/catch-up (the single-slot prefill is not factor-aware: a swap
 would zero dense rows that subsequent chunks still attend).
 """
 
@@ -80,7 +80,7 @@ class StepCostModel:
     memory-bound (one pass over weights + caches regardless of how many
     slots ride along), which is exactly why compression-bought concurrency
     raises aggregate tokens/sec — more tokens amortize the same base."""
-    prefill_base_us: float = 150.0    # per masked single-slot dispatch
+    prefill_base_us: float = 150.0    # per single-slot prefill dispatch
     prefill_per_token_us: float = 25.0
     decode_base_us: float = 850.0     # per batched decode step
     decode_per_token_us: float = 35.0  # per live slot in the step

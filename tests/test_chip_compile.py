@@ -5,7 +5,9 @@ Interpret mode hides what only the chip's compiler refuses: casts Mosaic has
 no lowering for, block shapes off the (8, 128) tiling, fast-memory budgets.
 Each test compiles one kernel at the shapes the chip runs (the paper's rSVD
 sketch, qwen3-0.6b decode and prefill attention) and asserts the compiled
-program holds the kernel (``tpu_custom_call``).  Nothing runs.
+program holds the kernel (``tpu_custom_call``).  The serving pool's prefill
+program is compiled too, to show that it keeps the pool in place.  Nothing
+runs.
 
 The topology is described inside a module fixture and never at import: only
 one process may load the TPU compiler's library at a time, so under several
@@ -14,15 +16,20 @@ compile in this one file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs.archs import ARCHS
 from repro.core import projection as proj
 from repro.kernels import ops
 from repro.kernels import shgemm as _k
+from repro.models import cache as cache_mod
+from repro.models import transformer as T
+from repro.serve.model_step import make_prefill_chunk
 
 # PAPER_RSVD sketch: n = 4096, p_hat = rank 256 + oversample 10
 N, P_HAT = 4096, 266
@@ -143,3 +150,32 @@ def test_fp16_omega_refused_by_kernels_compiled_by_xla(spec):
     hlo = _compiled_text(lambda a, b: proj.project(a, b, method="shgemm"),
                          a, b16)
     assert "tpu_custom_call" not in hlo
+
+
+def test_prefill_chunk_keeps_the_pool_in_place(spec):
+    """The serving cell's prefill program (qwen3-0.6b widths, a 4 x 1024
+    bf16 pool, chunk 8; two layers suffice) aliases every cache leaf to its
+    output and selects over no pool-sized array: it writes the slot's rows
+    into the donated pool instead of merging a whole new pool.  Nor does it
+    hold all layers of the slot at once: each layer reads its rows from the
+    pool and only the chunk's rows come back."""
+    slots, seq, chunk = 4, 1024, 8
+    cfg = ARCHS["qwen3-0.6b"].with_(n_layers=2, param_dtype="bfloat16")
+    params = {k: spec(v.shape, v.dtype)
+              for k, v in T.abstract_params(cfg).items()}
+    cache = cache_mod.build_cache(cfg, slots, seq, make=spec)
+    i32 = spec((), jnp.int32)
+    program, _ = make_prefill_chunk(cfg)
+    hlo = program.lower(
+        params, cache, spec((chunk,), jnp.int32), i32, i32
+    ).compile().as_text()
+    n_cache = len(jax.tree.leaves(cache))
+    aliased = re.search(r"input_output_alias=\{(.*?)\}, entry_", hlo)
+    assert aliased and aliased.group(1).count("may-alias") == n_cache
+    pool = {"bf16[" + ",".join(map(str, x.shape)) + "]"
+            for x in jax.tree.leaves(cache)}
+    assert pool == {f"bf16[2,{slots},{seq},{KV},{HD}]"}
+    selects = [ln for ln in hlo.splitlines()
+               if " select(" in ln and any(p in ln for p in pool)]
+    assert not selects, selects[:2]
+    assert f"bf16[2,1,{seq},{KV},{HD}]" not in hlo
