@@ -7,8 +7,9 @@ For each seed it runs the cell as ``run.py`` does (set-up, a window of
 ``--seconds`` at the cell's own load), then prints one JSON line with the
 numbers compared for what the window produced and for the control: the
 plain reference computed one precision below the configuration's, put in
-the program's place (rSVD: three bf16 passes for float32 at ``HIGHEST``;
-serving: fp8 weights for bfloat16).  Benchmark runs never run this.
+the program's place.  The driver names its control in its ``control``
+attribute (rSVD ``high``: three bf16 passes for float32 at ``HIGHEST``;
+serving ``fp8``: fp8 weights for bfloat16).  Benchmark runs never run this.
 """
 
 from __future__ import annotations
@@ -23,13 +24,11 @@ from pathlib import Path
 
 ROOT = Path(__file__).resolve().parents[1]
 
-CONTROLS = {"rsvd": "high", "serve": "fp8"}
-
 
 def readings(root: Path, workload: str, seed: int, seconds: float,
              control: str | None = None) -> dict:
     """Run ``workload`` once with ``seed`` and return the program's numbers
-    and the control's."""
+    and the control's: ``control``, else the one its driver names."""
     from chipbench import run as runmod
     from chipbench.spans import Spans
     _, cell, config, traffic, mod = runmod.load_cell(root, workload)
@@ -40,7 +39,7 @@ def readings(root: Path, workload: str, seed: int, seconds: float,
     drv.setup()
     drv.window(seconds)
     drv.release()
-    kind = control or CONTROLS[config["kind"]]
+    kind = control or drv.control
     out = {"seed": seed, "program": drv.readings(),
            "control": {"kind": kind, **drv.readings(kind)}}
     del drv

@@ -24,6 +24,8 @@ from chipbench.references import rsvd as ref
 
 
 class Driver:
+    control = "high"          # the reference in three bf16 passes (readings)
+
     def __init__(self, *, config: dict, traffic: dict, seed: int, spans,
                  trace: bool):
         if traffic["kind"] != "closed_loop":
@@ -38,13 +40,25 @@ class Driver:
     def _key(self, i: int):
         return jnp.asarray(loadgen.key_words(self.seed, 1000 + i))
 
-    def _call(self, i: int):
+    def _program(self, i: int, key) -> tuple:
+        """``repro.core.rsvd.rsvd`` on resident matrix ``i`` (modulo their
+        count) with ``key``: the jitted function, its array arguments, its
+        static arguments."""
         from repro.core import rsvd as program
         c = self.cfg
-        a = self.mats[i % len(self.mats)]
-        return program.rsvd(self._key(i), a, c["rank"],
-                            oversample=c["oversample"],
-                            power_iters=c["power_iters"], **self._projection())
+        return (program.rsvd, (key, self.mats[i % len(self.mats)]),
+                {"rank": c["rank"], "oversample": c["oversample"],
+                 "power_iters": c["power_iters"], **self._projection()})
+
+    def _call(self, i: int):
+        fn, args, static = self._program(i, self._key(i))
+        return fn(*args, **static)
+
+    def scoped_call(self, i: int) -> tuple:
+        """Call ``i`` of the scope readers' own trace (``scopes.py``): the
+        window's program at its arguments, with keys it never draws."""
+        return self._program(i, jnp.asarray(
+            loadgen.key_words(self.seed, 5000 + i)))
 
     def _projection(self) -> dict:
         c = self.cfg

@@ -121,6 +121,8 @@ class _Clock:
 
 
 class Driver:
+    control = "fp8"           # the reference on fp8 weights (``readings``)
+
     def __init__(self, *, config: dict, traffic: dict, seed: int, spans,
                  trace: bool):
         if traffic["kind"] != "backlog":

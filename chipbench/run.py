@@ -6,8 +6,13 @@ The harness is driven by data.  It finds the cell in ``BENCHMARK.json``,
 loads ``chipbench/configs/<config>.json`` and ``chipbench/traffic/<mix>.json``,
 imports the driver ``chipbench/drivers/<kind>.py`` that the configuration's
 ``kind`` names and, for every metric the cell reports, the reader
-``chipbench/metrics/<metric>.py``.  A new driver, configuration, traffic mix
-or metric is a new file; nothing here names one.
+``chipbench/metrics/<metric>.py``.  A driver also names its control
+(``control.py``) and the program its scope readers trace (``scopes.py``).
+So a new cell is new files (configuration, traffic mix, driver, metric
+readers and its tiny counterpart ``tests/chipbench/tiny/<cell>.json``) and
+entries appended to BENCHMARK.json.  The harness names no cell or kind, and
+its tests find the cells from those files; a test names a cell only where it
+checks one kind, or the look for a chip, on purpose.
 
 One run: set-up (inputs and weights made on the device from the seed, every
 shape the cell uses compiled or loaded from the persistent cache), then a

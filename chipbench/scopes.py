@@ -9,8 +9,12 @@ compiled program's HLO text maps the trace's operations to scopes.
 
 The benchmark's own trace of the window is reduced to op names without
 their instruction numbers (``chipbench/trace.py``), so the readers here
-take a short trace of their own after the window: the compiled program at
-the cell's arguments, called back to back as the window calls it.
+take a short trace of their own after the window: the program the cell's
+driver names, compiled at the cell's arguments and called back to back as
+the window calls it.  A driver names it in ``scoped_call(i) -> (jitted
+function, array arguments, static arguments)``, call ``i`` of that trace;
+the function's scopes are ``repro.tracing.SCOPES[<its name>]`` and its
+programs in the trace are ``jit_<its name>``.
 """
 
 from __future__ import annotations
@@ -21,14 +25,10 @@ import shutil
 from collections import Counter, defaultdict, deque
 from pathlib import Path
 
-from chipbench import loadgen
 from chipbench.trace import module_base, self_times
 
 ROOT = Path(__file__).resolve().parents[1]
 REPS = 20                     # calls in the scopes' own trace
-# the scopes of ``repro.core.rsvd.rsvd``, one per line of Algorithm 1
-RSVD_SCOPES = ("rsvd.sketch", "rsvd.power", "rsvd.qr", "rsvd.project_b",
-               "rsvd.small_svd", "rsvd.lift_u")
 
 _DEFINITION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=(.*)$")
 _METADATA = re.compile(r"\bmetadata=\{[^}]*\}")
@@ -126,48 +126,60 @@ def scope_ms(runs: int, seconds: dict, scope_of: dict) -> dict[str, float]:
 _MEASURED: dict[int, dict | None] = {}
 
 
-def rsvd_scope_ms(run) -> dict[str, float] | None:
-    """Device ms per ``rsvd`` call by scope at the cell's arguments, from
-    a trace of ``REPS`` calls made after the window (measured once per run).
-    None without a traced run, or for a program whose ``rsvd`` carries no
-    scopes."""
+def scope_ms_of(run) -> dict[str, float] | None:
+    """Device ms per call of the driver's scoped program by scope at the
+    cell's arguments, from a trace of ``REPS`` calls made after the window
+    (measured once per run).  None without a traced run, for a driver with
+    no scoped program, or for a program that carries no scopes."""
     if run.trace is None:
         return None
     if id(run) not in _MEASURED:
-        _MEASURED[id(run)] = _measure_rsvd(run)
+        _MEASURED[id(run)] = _measure(run.driver)
     return _MEASURED[id(run)]
 
 
-def _measure_rsvd(run) -> dict[str, float] | None:
-    import jax
-    import jax.numpy as jnp
-    from repro.core import rsvd as program
-    d, c = run.driver, run.config
-    # keys the window never draws
-    keys = [jnp.asarray(loadgen.key_words(d.seed, 5000 + i))
-            for i in range(REPS)]
-    compiled = program.rsvd.lower(
-        keys[0], d.mats[0], c["rank"], oversample=c["oversample"],
-        power_iters=c["power_iters"], method=c["method"], dist=c["dist"],
-        omega_dtype=getattr(jnp, c["omega_dtype"])).compile()
-    scope_of = instruction_scopes(compiled.as_text(), RSVD_SCOPES)
+def scoped_program(driver):
+    """``(module, compiled, scope_of, calls)`` for the driver's scoped
+    program: its programs' name in a trace, the program compiled at call 0's
+    arguments, each HLO instruction's scope and the array arguments of the
+    ``REPS`` calls; None where the driver names no program or the program
+    has no scopes in ``repro.tracing.SCOPES``."""
+    from repro import tracing
+    if not hasattr(driver, "scoped_call"):
+        return None
+    calls = [driver.scoped_call(i) for i in range(REPS)]
+    fn, args, static = calls[0]
+    name = fn.__name__
+    if name not in tracing.SCOPES:
+        return None
+    compiled = fn.lower(*args, **static).compile()
+    scope_of = instruction_scopes(compiled.as_text(), tracing.SCOPES[name])
     if not scope_of:
         return None
+    return f"jit_{name}", compiled, scope_of, [a for _, a, _ in calls]
+
+
+def _measure(driver) -> dict[str, float] | None:
+    import jax
+    program = scoped_program(driver)
+    if program is None:
+        return None
+    module, compiled, scope_of, calls = program
     out_dir = ROOT / ".chipbench" / "scopes"
     shutil.rmtree(out_dir, ignore_errors=True)
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
     opts.enable_hlo_proto = False
-    jax.block_until_ready(compiled(keys[0], d.mats[0]))
+    jax.block_until_ready(compiled(*calls[0]))
     jax.profiler.start_trace(str(out_dir), profiler_options=opts)
     try:
-        for i, key in enumerate(keys):
-            jax.block_until_ready(compiled(key, d.mats[i % len(d.mats)]))
+        for args in calls:
+            jax.block_until_ready(compiled(*args))
     finally:
         jax.profiler.stop_trace()
     files = sorted(out_dir.glob("**/*.xplane.pb"))
     try:
-        runs, seconds = (self_time_by_instruction(files[-1], "jit_rsvd")
+        runs, seconds = (self_time_by_instruction(files[-1], module)
                          if files else (0, {}))
     finally:
         shutil.rmtree(out_dir, ignore_errors=True)
@@ -175,7 +187,7 @@ def _measure_rsvd(run) -> dict[str, float] | None:
 
 
 def read_scope(run, scope: str) -> float | None:
-    """One scope's device ms per ``rsvd`` call; None where the program has
-    no such scope."""
-    ms = rsvd_scope_ms(run)
+    """One scope's device ms per call of the driver's scoped program; None
+    where the program has no such scope."""
+    ms = scope_ms_of(run)
     return None if ms is None or scope not in ms else ms[scope]

@@ -21,9 +21,8 @@ def _result(root, cell, capsys) -> dict:
     return json.loads(out.out.strip().splitlines()[-1])
 
 
-def test_sound_runs_are_correct(tiny_root, capsys):
-    for cell in ("tiny.rsvd", "tiny.batch"):
-        assert _result(tiny_root, cell, capsys)["correct"] is True
+def test_sound_runs_are_correct(tiny_root, tiny_cell, capsys):
+    assert _result(tiny_root, tiny_cell["name"], capsys)["correct"] is True
 
 
 def test_rsvd_answer_altered(tiny_root, capsys, monkeypatch):

@@ -1,5 +1,6 @@
 """BENCHMARK.json against the benchmark's rules, and the harness finding a
-new driver, configuration, traffic mix and metric from new files alone."""
+new driver, configuration, traffic mix and metric from new files alone:
+the cell's run, its tiny counterpart, its control and its scoped program."""
 
 from __future__ import annotations
 
@@ -11,6 +12,7 @@ from contextlib import redirect_stdout
 from pathlib import Path
 
 import pytest
+from conftest import make_tiny_root, tiny_mismatches
 
 ROOT = Path(__file__).resolve().parents[2]
 MANIFEST = json.loads((ROOT / "BENCHMARK.json").read_text())
@@ -134,6 +136,14 @@ def test_each_cell_reports_enough_and_moves_are_reported():
     assert all(len(v) == 1 for v in layers.values()), layers
 
 
+def test_every_cell_has_one_tiny_counterpart():
+    """Each cell runs on the CPU through its tiny counterpart
+    ``tests/chipbench/tiny/<cell>.json``, and each such file stands for a
+    cell."""
+    problems = tiny_mismatches()
+    assert not problems, "; ".join(problems)
+
+
 def test_shares_of_a_peak_are_named_for_it():
     for m in MANIFEST["per_layer"]:
         if m["name"].split(".")[0].endswith("_roofline") or \
@@ -224,3 +234,165 @@ def test_new_files_alone_are_found_by_name(tmp_path, monkeypatch, trace):
     else:
         assert set(result["metrics"]) == {"setup_s", "dummy_rate"}
         assert result["metrics"]["dummy_rate"]["value"] == 42.5
+
+
+SCOPED_DRIVER = '''
+import time
+
+import jax
+import jax.numpy as jnp
+
+
+@jax.jit
+def dummy_square(x):
+    with jax.named_scope("dummy.square"):
+        return x * x
+
+
+class Driver:
+    control = "halved"
+
+    def __init__(self, *, config, traffic, seed, spans, trace):
+        self.config, self.traffic, self.seed = config, traffic, seed
+        self.spans = spans
+
+    def _x(self, i):
+        return jnp.full((self.config["size"],), float(i % 7), jnp.float32)
+
+    def scoped_call(self, i):
+        return dummy_square, (self._x(i),), {}
+
+    def setup(self):
+        jax.block_until_ready(dummy_square(self._x(0)))
+
+    def window(self, seconds):
+        self.calls = self.traffic["calls"]
+        with self.spans("window"):
+            t0 = time.perf_counter()
+            self.out = [dummy_square(self._x(i)) for i in range(self.calls)]
+            jax.block_until_ready(self.out)
+            self.window_s = time.perf_counter() - t0
+
+    @property
+    def attempted(self):
+        return self.calls
+
+    failed = 0
+
+    def release(self):
+        pass
+
+    def readings(self, control=None):
+        scale = 0.5 if control == "halved" else 1.0
+        gaps = [jnp.max(jnp.abs(scale * y - self._x(i) ** 2))
+                for i, y in enumerate(self.out)]
+        return {"gap": max(float(g) for g in gaps)}
+
+    def verify(self):
+        got = self.readings()
+        return [(k, got[k], float(v))
+                for k, v in self.config["limits"].items()]
+'''
+SCOPED_READER = ("from chipbench import scopes\n\n\ndef read(run):\n"
+                 "    return scopes.read_scope(run, 'dummy.square')\n")
+TINY_DUMMY = {"name": "tiny.dummy", "control": "halved",
+              "config_name": "tiny-dummy",
+              "config": {"kind": "dummy_scoped", "size": 8,
+                         "limits": {"gap": 0.0}},
+              "traffic_name": "tiny-dummy-mix",
+              "traffic": {"kind": "closed_loop", "calls": 5}}
+
+
+def _new_kind_source(src: Path) -> Path:
+    """A copy of the repo's benchmark files with a new kind added as new
+    files and entries appended to BENCHMARK.json: a driver with a control
+    and a scoped program, a configuration, a traffic mix, a cell appended
+    to ``factor_ms``'s cells, a per-layer metric and the tiny counterpart."""
+    shutil.copytree(ROOT / "chipbench", src / "chipbench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    shutil.copytree(ROOT / "tests" / "chipbench" / "tiny",
+                    src / "tests" / "chipbench" / "tiny")
+    bench = src / "chipbench"
+    (bench / "drivers" / "dummy_scoped.py").write_text(SCOPED_DRIVER)
+    (bench / "configs" / "dummy-cfg.json").write_text(json.dumps(
+        {"kind": "dummy_scoped", "size": 4096, "limits": {"gap": 0.0}}))
+    (bench / "traffic" / "dummy-mix.json").write_text(json.dumps(
+        {"kind": "closed_loop", "calls": 50}))
+    (bench / "metrics" / "dummy_ms.scoped.py").write_text(SCOPED_READER)
+    (src / "tests" / "chipbench" / "tiny" / "dummy.cell.json").write_text(
+        json.dumps(TINY_DUMMY))
+    manifest = json.loads(json.dumps(MANIFEST))
+    manifest["configs"].append({"name": "dummy-cfg", "source": "none",
+                                "file": "chipbench/configs/dummy-cfg.json",
+                                "reduced": [], "why": "test"})
+    manifest["workloads"].append({"name": "dummy.cell", "config": "dummy-cfg",
+                                  "traffic": "dummy-mix", "chips": 1,
+                                  "why": "test"})
+    e2e = {m["name"]: m for m in manifest["end_to_end"]}
+    e2e["factor_ms"]["workloads"].append("dummy.cell")
+    manifest["per_layer"].append({
+        "name": "dummy_ms.scoped", "unit": "ms", "better": "lower",
+        "source": "device_trace", "layer": "dummy", "moves": "factor_ms",
+        "workloads": ["dummy.cell"]})
+    (src / "BENCHMARK.json").write_text(json.dumps(manifest))
+    return src
+
+
+def test_new_kind_reaches_its_tiny_cell_control_and_scopes(
+        tmp_path, monkeypatch):
+    """A new kind that exists only as new files and appended entries builds
+    a tiny checkout, runs, gives its control's readings and has its
+    program's instructions mapped to its scope, with no edit to a file of
+    the harness or of its tests."""
+    from chipbench import control, run, scopes
+    from chipbench import trace as trace_mod
+    from repro import tracing
+    src = _new_kind_source(tmp_path / "src")
+    before = tiny_mismatches()            # the repo's own, if it has any
+    assert tiny_mismatches(src) == before
+    root = make_tiny_root(tmp_path / "checkout", src)
+    tiny = json.loads((root / "BENCHMARK.json").read_text())
+    names = [w["name"] for w in tiny["workloads"]]
+    assert "tiny.dummy" in names
+    e2e = {m["name"]: m for m in tiny["end_to_end"]}
+    assert e2e["factor_ms"]["workloads"][-1] == "tiny.dummy"
+    assert tiny["per_layer"][-1]["workloads"] == ["tiny.dummy"]
+
+    monkeypatch.setitem(tracing.SCOPES, "dummy_square", ("dummy.square",))
+    monkeypatch.setattr(trace_mod, "reduce", lambda path: _Summary())
+    seen = {}
+    for trace in (0, 1):
+        out = io.StringIO()
+        with redirect_stdout(out):
+            rc = run.main(["--workload", "tiny.dummy", "--seed",
+                           str(2 ** 40 + 5), "--seconds", "1", "--trace",
+                           str(trace)], root=root, require_chip=False,
+                          driver_hook=lambda d: seen.update(driver=d))
+        assert rc == 0
+        result = json.loads(out.getvalue().strip().splitlines()[-1])
+        assert result["correct"] is True and result["attempted"] == 5
+        assert result["checks"] == {"gap": {"value": 0.0, "limit": 0.0}}
+        # on the CPU the scopes' own trace has no device plane to read
+        assert set(result["metrics"]) == (
+            set() if trace else {"setup_s", "factor_ms"})
+
+    got = control.readings(root, "tiny.dummy", 3, 1.0)
+    assert got["control"]["kind"] == "halved"
+    assert got["program"]["gap"] == 0.0 < got["control"]["gap"]
+
+    module, compiled, scope_of, calls = scopes.scoped_program(seen["driver"])
+    assert module == "jit_dummy_square" and len(calls) == scopes.REPS
+    assert scope_of == scopes.instruction_scopes(compiled.as_text(),
+                                                 ("dummy.square",))
+    assert set(scope_of.values()) == {"dummy.square"}
+
+    # without its tiny file the cell is named by the one manifest check,
+    # and the tiny checkout of the other cells still builds
+    (src / "tests" / "chipbench" / "tiny" / "dummy.cell.json").unlink()
+    assert set(tiny_mismatches(src)) - set(before) == {
+        "cell dummy.cell has no tiny counterpart "
+        "tests/chipbench/tiny/dummy.cell.json"}
+    rest = json.loads((make_tiny_root(tmp_path / "rest", src)
+                       / "BENCHMARK.json").read_text())
+    assert [w["name"] for w in rest["workloads"]] == [
+        n for n in names if n != "tiny.dummy"]

@@ -19,6 +19,10 @@ from jax.profiler import ProfileData
 
 from chipbench import program_trace, scopes, trace
 from chipbench.run import ROOT, load_module
+from chipbench.spans import Spans
+from repro.tracing import SCOPES
+
+RSVD_SCOPES = SCOPES["rsvd"]
 
 US = 1000          # ns
 
@@ -170,7 +174,7 @@ ENTRY %main (a: f32[4]) -> f32[4] {
 def test_instruction_scopes_from_hlo_text():
     # a copy with no metadata takes its consumer's scope; one that feeds
     # no scoped instruction (copy.3 -> while.4) has none
-    assert scopes.instruction_scopes(HLO, scopes.RSVD_SCOPES) == {
+    assert scopes.instruction_scopes(HLO, RSVD_SCOPES) == {
         "p": "rsvd.sketch", "neg.9": "rsvd.sketch", "a": "rsvd.sketch",
         "copy.5": "rsvd.sketch", "fusion.1": "rsvd.sketch",
         "custom-call.2": "rsvd.qr"}
@@ -197,7 +201,7 @@ def test_self_time_per_instruction_inside_the_module(tmp_path):
     assert seconds == pytest.approx(
         {"fusion.1": 70e-6, "while.4": 20e-6, "custom-call.2": 110e-6})
     ms = scopes.scope_ms(runs, seconds,
-                         scopes.instruction_scopes(HLO, scopes.RSVD_SCOPES))
+                         scopes.instruction_scopes(HLO, RSVD_SCOPES))
     assert ms == pytest.approx({"rsvd.sketch": 0.035, "rsvd.qr": 0.055,
                                 "": 0.010})
     assert scopes.self_time_by_instruction(path, "jit_absent") == (0, {})
@@ -234,8 +238,12 @@ def test_scope_measurement_without_a_device_reads_nothing():
               "omega_dtype": "bfloat16"}
     mats = [jax.random.normal(jax.random.PRNGKey(i), (64, 64), jnp.float32)
             for i in range(2)]
-    run = SimpleNamespace(trace=object(), config=config,
-                          driver=SimpleNamespace(seed=3, mats=mats))
+    driver = load_module(ROOT / "chipbench" / "drivers" / "rsvd.py",
+                         "chipbench_driver_rsvd").Driver(
+        config=config, traffic={"kind": "closed_loop"}, seed=3,
+        spans=Spans(), trace=False)
+    driver.mats = mats
+    run = SimpleNamespace(trace=object(), config=config, driver=driver)
     try:
         assert _reader("qr_ms.rsvd").read(run) is None
     finally:

@@ -1,6 +1,7 @@
-"""Whole runs of the harness on the CPU at tiny sizes: each kind of cell
-comes out correct with its metrics and nothing compiled inside the window,
-and a run with no chip, or without the program, prints no result."""
+"""Whole runs of the harness on the CPU at tiny sizes: each cell's tiny
+counterpart comes out correct with its metrics and nothing compiled inside
+the window, and a run with no chip, or without the program, prints no
+result."""
 
 from __future__ import annotations
 
@@ -16,8 +17,7 @@ import pytest
 from chipbench import run
 
 ROOT = Path(__file__).resolve().parents[2]
-E2E = {"tiny.rsvd": {"setup_s", "factor_ms"},
-       "tiny.batch": {"setup_s", "tokens_per_s", "itl_ms_p95"}}
+CELLS = json.loads((ROOT / "BENCHMARK.json").read_text())["workloads"]
 
 
 def run_cell(root: Path, cell: str, capsys, seed: int = 2 ** 40 + 3,
@@ -30,12 +30,16 @@ def run_cell(root: Path, cell: str, capsys, seed: int = 2 ** 40 + 3,
     return json.loads(out.out.strip().splitlines()[-1]), out.err
 
 
-@pytest.mark.parametrize("cell", sorted(E2E))
-def test_tiny_cell_is_correct(tiny_root, cell, capsys):
+def test_tiny_cell_is_correct(tiny_root, tiny_cell, capsys):
+    cell = tiny_cell["name"]
+    manifest = json.loads((tiny_root / "BENCHMARK.json").read_text())
+    e2e = {m["name"] for m in manifest["end_to_end"]
+           if cell in m.get("workloads", [cell])}
+    assert "setup_s" in e2e and len(e2e) >= 2
     result, err = run_cell(tiny_root, cell, capsys)
     assert result["correct"] is True, result["checks"]
     assert result["failed"] == 0 and result["attempted"] > 0
-    assert set(result["metrics"]) == E2E[cell]
+    assert set(result["metrics"]) == e2e
     assert all(m["value"] > 0 for m in result["metrics"].values())
     assert "run: 0 program(s) compiled or loaded inside the window" in err
     assert list(result) == ["correct", "attempted", "failed", "metrics",
@@ -84,7 +88,7 @@ def test_no_chip_no_result(capsys):
     assert "needs 1 TPU chip" in out.err
 
 
-@pytest.mark.parametrize("cell", ["rsvd.paper", "serve.qwen3.batch"])
+@pytest.mark.parametrize("cell", CELLS, ids=[w["name"] for w in CELLS])
 def test_command_from_the_checkout_finds_its_files(cell):
     """The benchmark's command, run as the driver runs it (from the root,
     with no path set), loads the cell's driver and then stops at the look
@@ -92,11 +96,11 @@ def test_command_from_the_checkout_finds_its_files(cell):
     env = {k: v for k, v in os.environ.items() if k != "PYTHONPATH"}
     env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run(
-        [sys.executable, "chipbench/run.py", "--workload", cell, "--seed",
-         "1", "--seconds", "1"], cwd=ROOT, env=env, capture_output=True,
-        text=True, timeout=120)
+        [sys.executable, "chipbench/run.py", "--workload", cell["name"],
+         "--seed", "1", "--seconds", "1"], cwd=ROOT, env=env,
+        capture_output=True, text=True, timeout=120)
     assert proc.returncode == 1 and proc.stdout == "", proc.stderr[-2000:]
-    assert "needs 1 TPU chip" in proc.stderr
+    assert f"needs {cell['chips']} TPU chip" in proc.stderr
 
 
 def test_benchmark_files_alone_do_not_run(tmp_path):
